@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import Rows, Timer
 from repro.compression.latentcodec import compress_latent
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import DecodeBatcher
 from repro.vae.model import VAE, VAEConfig
 from repro.vae.serve import decode_ms_estimate
@@ -185,9 +186,9 @@ def quantized_rows(rows: Rows, smoke: bool = False) -> None:
     of ``StoreConfig.weight_dtype`` — :mod:`repro.vae.quantize`)."""
     from repro.kernels import autotune as at
     from repro.vae import quantize as Q
-    from repro.vae.model import demo_vae
+    from repro.vae.model import calibrated_vae
     latent, buckets = (8, 8, 4), (1, 2, 4, 8)
-    vae = demo_vae(seed=0, weight_dtype="bfloat16")
+    vae = calibrated_vae("demo", seed=0, weight_dtype="bfloat16")
     st = Q.decoder_storage(vae._params_for("bfloat16"))
     rows.add("decode.quantized.bf16_bytes_per_param",
              derived=round(st["bytes_per_param"], 2))
@@ -254,6 +255,7 @@ def main():
                     help="CI-sized fast-path A/B; writes BENCH_decode.json "
                          "at the repo root")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         trajectory(smoke=True).print()
         return

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import Rows, Timer
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -54,14 +55,12 @@ def run() -> Rows:
              derived=round(3 * s_mid * s_mid * 4 / 1e6, 0))
     rows.add("kernel.flash_attn.scores_bytes_flash_mb", derived=0)
 
-    # conv3x3 implicit GEMM: VMEM tiling legality at decode shapes
-    from repro.kernels.conv3x3 import VMEM_BUDGET
+    # conv3x3 implicit GEMM: the kernel's own band sizing at decode shapes
+    # (fp32 activations, whole per-step VMEM footprint)
+    from repro.kernels.conv3x3 import band_rows, step_vmem_bytes
     for (hh, ww, cin) in ((128, 128, 512), (512, 512, 512), (1024, 1024, 128)):
-        rows_band = 32
-        while rows_band > 1 and (rows_band + 2) * (ww + 2) * cin * 2 \
-                > VMEM_BUDGET:
-            rows_band //= 2
-        vmem = (rows_band + 2) * (ww + 2) * cin * 2 / 2 ** 20
+        rows_band = band_rows(hh, ww, cin, 4, 32)
+        vmem = step_vmem_bytes(rows_band, ww, cin, 4) / 2 ** 20
         rows.add(f"kernel.conv3x3.{hh}x{ww}x{cin}.band_rows",
                  derived=rows_band)
         rows.add(f"kernel.conv3x3.{hh}x{ww}x{cin}.vmem_mb",
@@ -224,6 +223,7 @@ def main():
                     help="interpret-mode kernel sweep; writes "
                          "BENCH_kernels.json at the repo root")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         trajectory().print()
         return

@@ -23,6 +23,7 @@ import time
 import traceback
 
 from benchmarks.common import Rows
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     "bench_trace", "bench_storage", "bench_decode", "bench_kernels",
@@ -68,6 +69,7 @@ def main() -> None:
                     help="write BENCH_decode.json + BENCH_kernels.json at "
                          "the repo root and exit")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trajectory:
         trajectory()
         return

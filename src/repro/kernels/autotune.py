@@ -20,8 +20,7 @@ defaults are right on average and wrong per shape: the best band for a
   (tmp + rename), and loaded with a clean fall-back-to-defaults on a
   missing, corrupt, or stale-version file;
 * ``ops.py`` dispatch consults the process-wide *active* cache
-  (:func:`set_active_cache` — same process-global idiom as
-  ``ops.set_default_impl``) on every Pallas call, so ``prewarm_decode``
+  (:func:`set_active_cache`) on every Pallas call, so ``prewarm_decode``
   compiles the tuned shapes;
 * :class:`KernelAutotuner` is the serving-side driver: the engine notes
   each (bucket, latent shape) it decodes, and ``step(budget)`` tunes a
@@ -142,8 +141,8 @@ _ACTIVE: Optional[TuningCache] = None
 
 
 def set_active_cache(cache: Optional[TuningCache]) -> None:
-    """Install the process-wide cache ``ops.py`` dispatch consults (the
-    ``set_default_impl`` idiom: models never thread it explicitly)."""
+    """Install the process-wide cache ``ops.py`` dispatch consults
+    (models never thread it explicitly)."""
     global _ACTIVE
     _ACTIVE = cache
 
@@ -236,9 +235,11 @@ def _effective(kernel: str, spec: Dict[str, Any], rows: int,
     while cout % tc:
         tc //= 2
     if kernel == "upsample_conv3x3":
-        r = band_rows(h, w, cin + 4 * tc, itemsize, rows)
+        r = band_rows(h, w, cin, itemsize, rows, tc=tc, taps=8,
+                      out_factor=2)
     else:
-        r = band_rows(h, w, cin, itemsize, rows)
+        r = band_rows(h, w, cin, itemsize, rows, tc=tc,
+                      fused_gn=kernel != "conv3x3")
     return r, tc
 
 

@@ -11,7 +11,8 @@ Grid (n*hq, sq_tiles, skv_tiles): the kv axis is innermost/sequential; the
 output block and the fp32 (m, l, acc) running stats live in VMEM scratch
 revisited across kv steps.  Causal/window masking is computed from the
 absolute positions (q tiles are offset by skv - sq so q/k align at the
-sequence end); fully-masked kv tiles are skipped via block-level early-out.
+sequence end); fully-masked kv tiles are skipped via block-level early-out.  Both
+matmuls run at full fp32 precision (the decode is held to the fp32 oracle).
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -41,7 +43,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     q = q_ref[0].astype(jnp.float32) * scale          # [bq, d]
     k = k_ref[0].astype(jnp.float32)                  # [bkv, d]
-    s = q @ k.T                                       # [bq, bkv]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=HIGHEST,
+                            preferred_element_type=jnp.float32)  # [bq, bkv]
 
     if causal or window is not None:
         q_pos = (pl.program_id(1) * bq + jax.lax.broadcasted_iota(
@@ -61,7 +65,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     p = jnp.exp(s - m_new)                            # [bq, bkv]
     corr = jnp.exp(m_prev - m_new)                    # [bq, 1]
     l_new = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + p @ v_ref[0].astype(jnp.float32)
+    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+        p, v_ref[0].astype(jnp.float32), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
 

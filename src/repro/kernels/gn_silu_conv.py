@@ -10,17 +10,15 @@ memory term (see the roofline in :mod:`repro.vae.serve` and the traffic
 rows in ``benchmarks/bench_kernels.py``).
 
 Structure (GN stats must exist before the conv can run):
-  pass 1  grid (N, T): per-spatial-tile partial sums -> (sum, sumsq) [N, G]
-          (shared with :mod:`repro.kernels.gn_silu`);
-  pass 2  grid (N*nb, Cout/tc): per row-band, normalize + SiLU the band in
-          VMEM — including its halo rows, which are real neighbor pixels —
-          then accumulate the nine shifted (rows*W, Cin) x (Cin, tc) MXU
-          matmuls exactly as :mod:`repro.kernels.conv3x3` does.
-
-The conv's SAME zero-padding ring must stay zero *after* the activation
-(``silu(gn(0)) != 0``), so the kernel masks the ring: columns 0 and W+1
-always, the top halo row on an image's first band, the bottom halo row on
-its last.  Interior halo rows are neighbor data and are left normalized.
+  pass 1  per-channel partial sums -> per-image GN coefficients
+          (:func:`repro.kernels.gn_silu.gn_coefficients`, shared with
+          :mod:`repro.kernels.gn_silu`);
+  pass 2  grid (N*nb, Cout/tc): per row-band, normalize + SiLU the band
+          into a VMEM scratch — including its halo rows, which are real
+          neighbor pixels, and re-zeroing the conv's SAME padding ring
+          (``silu(gn(0)) != 0``) — then accumulate the nine shifted
+          (W, Cin) x (Cin, tc) MXU matmuls per output row exactly as
+          :mod:`repro.kernels.conv3x3` does (:func:`banded_conv`).
 """
 
 from __future__ import annotations
@@ -30,52 +28,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.conv3x3 import band_rows, materialize_bands
-from repro.kernels.gn_silu import _stats_kernel
-
-
-def _fused_kernel(x_ref, sum_ref, sq_ref, scale_ref, bias_ref, w_ref, *refs,
-                  rows: int, width: int, groups: int, eps: float,
-                  count: float, nb: int):
-    # refs is (b_ref, o_ref), or (s_ref, b_ref, o_ref) with a per-output-
-    # channel dequant scale (int8 weight storage)
-    s_ref, b_ref, o_ref = refs if len(refs) == 3 else (None, *refs)
-    band = pl.program_id(0) % nb
-    x = x_ref[0].astype(jnp.float32)                 # [rows+2, W+2, Cin]
-    cin = x.shape[-1]
-    cpg = cin // groups
-
-    mean = sum_ref[...] / count                      # [1, G]
-    var = sq_ref[...] / count - mean * mean
-    inv = jax.lax.rsqrt(var + eps)
-    mean_c = jnp.repeat(mean[0], cpg)                # [Cin]
-    inv_c = jnp.repeat(inv[0], cpg)
-    y = (x - mean_c) * inv_c * scale_ref[...].astype(jnp.float32) \
-        + bias_ref[...].astype(jnp.float32)
-    y = y * jax.nn.sigmoid(y)
-
-    # re-zero the conv's SAME padding ring (silu(gn(0)) != 0)
-    rr = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
-    cc = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
-    valid = (cc >= 1) & (cc <= width)
-    valid &= ~((rr == 0) & (band == 0))
-    valid &= ~((rr == rows + 1) & (band == nb - 1))
-    y = jnp.where(valid, y, 0.0)
-
-    acc = jnp.zeros_like(o_ref[0], dtype=jnp.float32)  # [rows, W, tc]
-    for dy in range(3):
-        for dx in range(3):
-            patch = y[dy:dy + rows, dx:dx + width, :]
-            tap = w_ref[dy, dx].astype(jnp.float32)    # [Cin, tc]
-            acc += jax.lax.dot_general(
-                patch.reshape(rows * width, -1), tap,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).reshape(rows, width, -1)
-    if s_ref is not None:
-        acc = acc * s_ref[...].astype(jnp.float32)
-    o_ref[0] = (acc + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+from repro.kernels.conv3x3 import banded_conv
+from repro.kernels.gn_silu import gn_coefficients
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "rows",
@@ -84,66 +39,13 @@ def _fused_kernel(x_ref, sum_ref, sq_ref, scale_ref, bias_ref, w_ref, *refs,
 def gn_silu_conv3x3(x: jax.Array, scale: jax.Array, bias: jax.Array,
                     w: jax.Array, b: Optional[jax.Array] = None,
                     groups: int = 32, eps: float = 1e-6, rows: int = 32,
-                    block_cout: int = 128, stats_tile: int = 512,
+                    block_cout: int = 128, stats_tile: int = 1024,
                     interpret: bool = False,
                     w_scale: Optional[jax.Array] = None) -> jax.Array:
     """``conv3x3(silu(group_norm(x)))`` fused.  x [N, H, W, Cin] NHWC,
     scale/bias [Cin], w [3, 3, Cin, Cout], b [Cout] -> [N, H, W, Cout]."""
-    n, h, width, cin = x.shape
-    cout = w.shape[-1]
-    if b is None:
-        b = jnp.zeros((cout,), x.dtype)
-
-    # -- pass 1: GN statistics (shared kernel with gn_silu) -----------------
-    hw = h * width
-    xf = x.reshape(n, hw, cin)
-    tile = min(stats_tile, hw)
-    while hw % tile:
-        tile //= 2
-    nt = hw // tile
-    stats_shape = jax.ShapeDtypeStruct((n, groups), jnp.float32)
-    sums, sqs = pl.pallas_call(
-        functools.partial(_stats_kernel, groups=groups),
-        grid=(n, nt),
-        in_specs=[pl.BlockSpec((1, tile, cin), lambda i, t: (i, t, 0))],
-        out_specs=[pl.BlockSpec((1, groups), lambda i, t: (i, 0)),
-                   pl.BlockSpec((1, groups), lambda i, t: (i, 0))],
-        out_shape=[stats_shape, stats_shape],
-        interpret=interpret,
-    )(xf)
-
-    # -- pass 2: normalize + SiLU + implicit-GEMM conv per row band ---------
-    rows = band_rows(h, width, cin, x.dtype.itemsize, rows)
-    tc = min(block_cout, cout)
-    while cout % tc:
-        tc //= 2
-    nb = h // rows
-
-    in_specs = [
-        pl.BlockSpec((1, rows + 2, width + 2, cin),
-                     lambda i, c: (i, 0, 0, 0)),
-        pl.BlockSpec((1, groups), lambda i, c: (i // nb, 0)),
-        pl.BlockSpec((1, groups), lambda i, c: (i // nb, 0)),
-        pl.BlockSpec((cin,), lambda i, c: (0,)),
-        pl.BlockSpec((cin,), lambda i, c: (0,)),
-        pl.BlockSpec((3, 3, cin, tc), lambda i, c: (0, 0, 0, c)),
-    ]
-    operands = [materialize_bands(x, rows), sums, sqs, scale, bias, w]
-    if w_scale is not None:
-        in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-        operands.append(w_scale)
-    in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-    operands.append(b)
-
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, rows=rows, width=width,
-                          groups=groups, eps=eps,
-                          count=float(hw * (cin // groups)), nb=nb),
-        grid=(n * nb, cout // tc),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, width, tc),
-                               lambda i, c: (i, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n * nb, rows, width, cout), x.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(n, h, width, cout)
+    mean_c, mul_c = gn_coefficients(x, scale, groups, eps, stats_tile,
+                                     interpret)
+    return banded_conv(x, w, b, rows=rows, block_cout=block_cout,
+                       interpret=interpret, w_scale=w_scale,
+                       gn=(mean_c, mul_c, bias.astype(jnp.float32)))

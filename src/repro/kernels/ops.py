@@ -1,13 +1,15 @@
 """Jitted entry points for the kernel layer with implementation dispatch.
 
 ``impl`` is one of
-  'xla'               pure-jnp reference (the oracle; default on CPU)
+  'xla'               pure-jnp reference (the oracle)
   'pallas'            Pallas TPU kernel (Mosaic; requires TPU)
   'pallas_interpret'  Pallas kernel body interpreted on CPU (correctness)
 
-The default is process-wide (``set_default_impl``) so models never thread
-the flag explicitly; the dry-run/compile paths stay on 'xla' while kernel
-tests pin 'pallas_interpret'.
+``impl=None`` is chosen from the platform: 'pallas' when JAX's default
+backend is a TPU, 'xla' elsewhere — so the served decode runs the Pallas
+kernels on the chip with no flag threaded through the models, and there
+is no quiet fallback to the oracle there.  Kernel tests pin
+'pallas_interpret'; reference checks pass 'xla' explicitly.
 
 Two cross-cutting paths live at this layer (not inside individual
 kernels), so every consumer gets them uniformly:
@@ -38,23 +40,17 @@ import jax.numpy as jnp
 from repro.kernels import autotune
 from repro.kernels import ref
 
-_DEFAULT_IMPL = "xla"
 _VALID = ("xla", "pallas", "pallas_interpret")
 
 
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    if impl not in _VALID:
-        raise ValueError(f"impl must be one of {_VALID}")
-    _DEFAULT_IMPL = impl
-
-
-def get_default_impl() -> str:
-    return _DEFAULT_IMPL
+def platform_impl() -> str:
+    """The implementation ``impl=None`` resolves to on this process's
+    default backend."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _resolve(impl: Optional[str], kernel: str) -> str:
-    impl = impl or _DEFAULT_IMPL
+    impl = impl or platform_impl()
     if impl not in _VALID:
         raise ValueError(
             f"{kernel}: impl must be one of {_VALID}, got {impl!r}")

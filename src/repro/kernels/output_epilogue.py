@@ -3,11 +3,12 @@
 The last stage of the VAE decode — ``conv_out(silu(gn(x)))`` followed by
 the serving-side clamp to [-1, 1] and quantization to displayable uint8 —
 previously ran as three ops with the float32 image crossing HBM (and the
-device boundary) at 4x the displayed bytes.  This kernel extends the fused
-res-block structure of :mod:`repro.kernels.gn_silu_conv` with the
-quantize epilogue, so the jitted decode's final write is the uint8 HWC
-image itself: 1/4 the output traffic, 1/4 the device->host transfer, and
-pixel-cache entries charged at their real (uint8) byte size.
+device boundary) at 4x the displayed bytes.  This kernel is the fused
+res-block kernel of :mod:`repro.kernels.gn_silu_conv` with the quantize
+epilogue (:func:`repro.kernels.conv3x3.banded_conv`, ``quantize=True``),
+so the jitted decode's final write is the uint8 HWC image itself: 1/4 the
+output traffic, 1/4 the device->host transfer, and pixel-cache entries
+charged at their real (uint8) byte size.
 
 Quantization is the paper's display mapping ``round((clip(y, -1, 1) + 1)
 * 127.5)`` computed in fp32 — identical on the oracle and the kernel, so
@@ -22,58 +23,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.conv3x3 import band_rows, materialize_bands
-from repro.kernels.gn_silu import _stats_kernel
-
-
-def quantize_u8(y: jax.Array) -> jax.Array:
-    """[-1, 1] float image -> uint8, the serving display mapping."""
-    yf = jnp.clip(y.astype(jnp.float32), -1.0, 1.0)
-    return jnp.round((yf + 1.0) * 127.5).astype(jnp.uint8)
-
-
-def _epilogue_kernel(x_ref, sum_ref, sq_ref, scale_ref, bias_ref, w_ref,
-                     *refs, rows: int, width: int, groups: int,
-                     eps: float, count: float, nb: int):
-    # refs is (b_ref, o_ref), or (s_ref, b_ref, o_ref) with a per-output-
-    # channel dequant scale (int8 weight storage)
-    s_ref, b_ref, o_ref = refs if len(refs) == 3 else (None, *refs)
-    band = pl.program_id(0) % nb
-    x = x_ref[0].astype(jnp.float32)                 # [rows+2, W+2, Cin]
-    cin = x.shape[-1]
-    cpg = cin // groups
-
-    mean = sum_ref[...] / count                      # [1, G]
-    var = sq_ref[...] / count - mean * mean
-    inv = jax.lax.rsqrt(var + eps)
-    mean_c = jnp.repeat(mean[0], cpg)                # [Cin]
-    inv_c = jnp.repeat(inv[0], cpg)
-    y = (x - mean_c) * inv_c * scale_ref[...].astype(jnp.float32) \
-        + bias_ref[...].astype(jnp.float32)
-    y = y * jax.nn.sigmoid(y)
-
-    # re-zero the conv's SAME padding ring (silu(gn(0)) != 0)
-    rr = jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
-    cc = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
-    valid = (cc >= 1) & (cc <= width)
-    valid &= ~((rr == 0) & (band == 0))
-    valid &= ~((rr == rows + 1) & (band == nb - 1))
-    y = jnp.where(valid, y, 0.0)
-
-    acc = jnp.zeros(o_ref.shape[1:], dtype=jnp.float32)  # [rows, W, tc]
-    for dy in range(3):
-        for dx in range(3):
-            patch = y[dy:dy + rows, dx:dx + width, :]
-            tap = w_ref[dy, dx].astype(jnp.float32)      # [Cin, tc]
-            acc += jax.lax.dot_general(
-                patch.reshape(rows * width, -1), tap,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32).reshape(rows, width, -1)
-    if s_ref is not None:
-        acc = acc * s_ref[...].astype(jnp.float32)
-    o_ref[0] = quantize_u8(acc + b_ref[...].astype(jnp.float32))
+from repro.kernels.conv3x3 import banded_conv
+from repro.kernels.gn_silu import gn_coefficients
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "rows",
@@ -82,68 +34,15 @@ def _epilogue_kernel(x_ref, sum_ref, sq_ref, scale_ref, bias_ref, w_ref,
 def output_epilogue(x: jax.Array, scale: jax.Array, bias: jax.Array,
                     w: jax.Array, b: Optional[jax.Array] = None,
                     groups: int = 32, eps: float = 1e-6, rows: int = 32,
-                    block_cout: int = 128, stats_tile: int = 512,
+                    block_cout: int = 128, stats_tile: int = 1024,
                     interpret: bool = False,
                     w_scale: Optional[jax.Array] = None) -> jax.Array:
     """``quantize_u8(conv3x3(silu(group_norm(x))))`` fused.  x [N, H, W,
     Cin] NHWC, scale/bias [Cin], w [3, 3, Cin, Cout], b [Cout] ->
     uint8 [N, H, W, Cout]."""
-    n, h, width, cin = x.shape
-    cout = w.shape[-1]
-    if b is None:
-        b = jnp.zeros((cout,), x.dtype)
-
-    # -- pass 1: GN statistics (shared kernel with gn_silu) -----------------
-    hw = h * width
-    xf = x.reshape(n, hw, cin)
-    tile = min(stats_tile, hw)
-    while hw % tile:
-        tile //= 2
-    nt = hw // tile
-    stats_shape = jax.ShapeDtypeStruct((n, groups), jnp.float32)
-    sums, sqs = pl.pallas_call(
-        functools.partial(_stats_kernel, groups=groups),
-        grid=(n, nt),
-        in_specs=[pl.BlockSpec((1, tile, cin), lambda i, t: (i, t, 0))],
-        out_specs=[pl.BlockSpec((1, groups), lambda i, t: (i, 0)),
-                   pl.BlockSpec((1, groups), lambda i, t: (i, 0))],
-        out_shape=[stats_shape, stats_shape],
-        interpret=interpret,
-    )(xf)
-
-    # -- pass 2: normalize + SiLU + conv + quantize per row band ------------
-    rows = band_rows(h, width, cin, x.dtype.itemsize, rows)
-    tc = min(block_cout, cout)
-    while cout % tc:
-        tc //= 2
-    nb = h // rows
-
-    in_specs = [
-        pl.BlockSpec((1, rows + 2, width + 2, cin),
-                     lambda i, c: (i, 0, 0, 0)),
-        pl.BlockSpec((1, groups), lambda i, c: (i // nb, 0)),
-        pl.BlockSpec((1, groups), lambda i, c: (i // nb, 0)),
-        pl.BlockSpec((cin,), lambda i, c: (0,)),
-        pl.BlockSpec((cin,), lambda i, c: (0,)),
-        pl.BlockSpec((3, 3, cin, tc), lambda i, c: (0, 0, 0, c)),
-    ]
-    operands = [materialize_bands(x, rows), sums, sqs, scale, bias, w]
-    if w_scale is not None:
-        in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-        operands.append(w_scale)
-    in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-    operands.append(b)
-
-    out = pl.pallas_call(
-        functools.partial(_epilogue_kernel, rows=rows, width=width,
-                          groups=groups, eps=eps,
-                          count=float(hw * (cin // groups)), nb=nb),
-        grid=(n * nb, cout // tc),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, width, tc),
-                               lambda i, c: (i, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n * nb, rows, width, cout),
-                                       jnp.uint8),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(n, h, width, cout)
+    mean_c, mul_c = gn_coefficients(x, scale, groups, eps, stats_tile,
+                                     interpret)
+    return banded_conv(x, w, b, rows=rows, block_cout=block_cout,
+                       interpret=interpret, w_scale=w_scale,
+                       gn=(mean_c, mul_c, bias.astype(jnp.float32)),
+                       quantize=True)

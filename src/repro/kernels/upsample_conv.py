@@ -19,8 +19,9 @@ pixel) turns each phase into an independent 2x2 conv on ``x``:
 
 so the fused op is 4 phases x 4 taps = 16 MXU matmuls over ``rows*W``
 pixels vs 9 matmuls over ``4*rows*W`` for conv-on-upsampled: **2.25x fewer
-MACs** on top of the traffic win.  The four ``[rows, W, tc]`` phase
-accumulators interleave to the ``[2*rows, 2*W, tc]`` output block in VMEM.
+MACs** on top of the traffic win.  A grid step computes one column phase
+``pj`` (both row phases); the phases land in an output layout whose
+memory order is already the interleaved ``[2H, 2W]`` image.
 
 Grid/banding follows :mod:`repro.kernels.conv3x3`: the wrapper stages
 halo-padded input row bands once in HBM; zero halos at image edges are
@@ -37,7 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.conv3x3 import band_rows, materialize_bands
+from repro.kernels.conv3x3 import (band_rows, compiler_params, cout_tile,
+                                   materialize_bands, tap_dot)
 
 #: tap groups per phase: phase p sums these dy (dx) taps into its 2 row
 #: (col) offsets — offset index a lands on band row ``p + a``
@@ -67,34 +69,31 @@ def _upsample_conv_kernel(x_ref, w_ref, *refs, rows: int, width: int):
     # refs is (b_ref, o_ref), or (s_ref, b_ref, o_ref) with a per-output-
     # channel dequant scale (int8 weight storage)
     s_ref, b_ref, o_ref = refs if len(refs) == 3 else (None, *refs)
-    x = x_ref[0]                                     # [rows+2, W+2, Cin]
-    tc = o_ref.shape[-1]
-    bias = b_ref[...].astype(jnp.float32)
+    bias = b_ref[...].astype(jnp.float32)            # [1, tc]
     w_scale = None if s_ref is None else s_ref[...].astype(jnp.float32)
-    row_phases = []
-    for pi in range(2):
-        col_phases = []
-        for pj in range(2):
-            acc = jnp.zeros((rows, width, tc), jnp.float32)
-            for a in range(2):
-                for b in range(2):
-                    patch = x[pi + a:pi + a + rows,
-                              pj + b:pj + b + width, :].astype(jnp.float32)
-                    tap = w_ref[pi, pj, a, b].astype(jnp.float32)  # [Cin, tc]
-                    acc += jax.lax.dot_general(
-                        patch.reshape(rows * width, -1), tap,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ).reshape(rows, width, -1)
-            if w_scale is not None:
-                acc = acc * w_scale
-            col_phases.append(acc + bias)
-        # column interleave: out[.., 2j+pj] = col_phases[pj][.., j]
-        row_phases.append(jnp.stack(col_phases, axis=2)
-                          .reshape(rows, 2 * width, -1))
-    # row interleave: out[2i+pi] = row_phases[pi][i]
-    out = jnp.stack(row_phases, axis=1).reshape(2 * rows, 2 * width, -1)
-    o_ref[0] = out.astype(o_ref.dtype)
+
+    def column_phase(pj: int):
+        # one column phase per grid step: output rows 2i+pi, columns
+        # 2j+pj of the band; w_ref is [pi, a, b, Cin, tc] for this pj
+        def out_row(r, carry):
+            for pi in range(2):
+                acc = None
+                for a in range(2):
+                    for b in range(2):
+                        d = tap_dot(x_ref[0, r + pi + a,
+                                          pl.ds(pj + b, width), :],
+                                    w_ref[pi, a, b])
+                        acc = d if acc is None else acc + d
+                if w_scale is not None:
+                    acc = acc * w_scale
+                o_ref[0, r, pi] = (acc + bias).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, rows, out_row, 0)
+
+    pj = pl.program_id(1)
+    for phase in (0, 1):
+        pl.when(pj == phase)(functools.partial(column_phase, phase))
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "block_cout",
@@ -110,18 +109,20 @@ def upsample_conv3x3(x: jax.Array, w: jax.Array,
     int8 ``w`` (with ``w_scale`` [Cout]) is phase-collapsed in int16 — a
     collapsed tap sums at most 4 int8 values, which int16 holds exactly,
     and the shared per-channel scale distributes over the sum — then
-    dequantized on the phase accumulators in VMEM."""
+    dequantized on the phase accumulators in VMEM.
+
+    The kernel writes ``[N*nb, rows, 2 (pi), W, 2 (pj) * Cout]``, which
+    is the ``[N, 2H, 2W, Cout]`` output's own memory order, so the phase
+    interleave costs a free reshape instead of an in-VMEM shuffle."""
     n, h, width, cin = x.shape
     cout = w.shape[-1]
     if b is None:
         b = jnp.zeros((cout,), x.dtype)
 
-    # the output block is 4x the input band's area: budget both by sizing
-    # the band as if the input carried the output's channel load too
-    tc = min(block_cout, cout)
-    while cout % tc:
-        tc //= 2
-    rows = band_rows(h, width, cin + 4 * tc, x.dtype.itemsize, rows)
+    tc = cout_tile(cout, block_cout)
+    nc = cout // tc
+    rows = band_rows(h, width, cin, x.dtype.itemsize, rows, tc=tc, taps=8,
+                     out_factor=2)
     nb = h // rows
     if w.dtype == jnp.int8:
         wc = phase_weights(w.astype(jnp.int16))      # exact: |sum| <= 4*127
@@ -130,25 +131,26 @@ def upsample_conv3x3(x: jax.Array, w: jax.Array,
 
     in_specs = [
         pl.BlockSpec((1, rows + 2, width + 2, cin),
-                     lambda i, c: (i, 0, 0, 0)),
-        pl.BlockSpec((2, 2, 2, 2, cin, tc),
-                     lambda i, c: (0, 0, 0, 0, 0, c)),
+                     lambda i, p, c: (i, 0, 0, 0)),
+        pl.BlockSpec((2, None, 2, 2, cin, tc),
+                     lambda i, p, c: (0, p, 0, 0, 0, c)),
     ]
     operands = [materialize_bands(x, rows), wc]
     if w_scale is not None:
-        in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-        operands.append(w_scale)
-    in_specs.append(pl.BlockSpec((tc,), lambda i, c: (c,)))
-    operands.append(b)
+        in_specs.append(pl.BlockSpec((1, tc), lambda i, p, c: (0, c)))
+        operands.append(w_scale.reshape(1, cout))
+    in_specs.append(pl.BlockSpec((1, tc), lambda i, p, c: (0, c)))
+    operands.append(b.reshape(1, cout))
 
     out = pl.pallas_call(
         functools.partial(_upsample_conv_kernel, rows=rows, width=width),
-        grid=(n * nb, cout // tc),
+        grid=(n * nb, 2, nc),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 2 * rows, 2 * width, tc),
-                               lambda i, c: (i, 0, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((n * nb, 2 * rows, 2 * width, cout),
+        out_specs=pl.BlockSpec((1, rows, 2, width, tc),
+                               lambda i, p, c: (i, 0, 0, 0, p * nc + c)),
+        out_shape=jax.ShapeDtypeStruct((n * nb, rows, 2, width, 2 * cout),
                                        x.dtype),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*operands)
     return out.reshape(n, 2 * h, 2 * width, cout)

@@ -24,11 +24,14 @@ Misses do not decode one-by-one: they accumulate in a ``DecodeBatcher``
 queue where duplicate in-flight object ids coalesce into a single decode
 (single-flight), then flush as batches padded up to a small set of
 bucketed batch sizes (default 1/2/4/8) so ``jax.jit`` compiles once per
-bucket instead of once per arrival pattern.  Per-image wall-clock
-(batch time / real images in the batch) feeds the marginal-hit tuner's
-EWMAs, closing the paper's feedback loop on real measurements.  Decode is
-deterministic per image, so bucketed batching (and its padding) returns
-bit-identical pixels to a batch-1 decode of the same latent.
+bucket and latent shape instead of once per arrival pattern.  Per-image
+wall-clock (batch time / real images in the batch) feeds the marginal-hit
+tuner's EWMAs, closing the paper's feedback loop on real measurements.
+Decode is deterministic per image and shape: a padded slot never changes
+a real image's pixels, but batches of different size are different
+compiled programs, so a bucketed decode agrees with a batch-1 decode of
+the same latent to the uint8 contract of the whole fast path — within
+±1 LSB — not bit for bit.
 """
 
 from __future__ import annotations
@@ -39,7 +42,6 @@ import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.compression.latentcodec import compress_latent, decompress_latent
@@ -140,13 +142,14 @@ class DecodeBatcher:
     ids coalesce into one decode (single-flight).  :meth:`flush` drains the
     queue in FIFO order as batches, each padded up to the smallest
     configured bucket that fits so the jitted decode sees only
-    ``len(buckets)`` distinct batch shapes.  Padding repeats the last real
-    latent — the decode is per-image independent and deterministic, so
-    padded slots never perturb the real outputs.
+    ``len(buckets)`` distinct batch shapes per latent shape.  Padding
+    repeats the last real latent — the decode is per-image independent,
+    so padded slots never perturb the real outputs; across buckets the
+    pixels agree within ±1 LSB (each bucket is its own compiled program).
 
     The regeneration fast path (PR 4) layers three optimizations on top:
 
-    * ``pixel_format='uint8'`` routes through the donated
+    * ``pixel_format='uint8'`` routes through
       :meth:`VAE.decode_u8` — one compiled graph from normalized latent to
       displayable uint8 bytes (1/4 the device->host transfer and pixel
       cache charge of float32);
@@ -179,7 +182,8 @@ class DecodeBatcher:
         # oid -> (blob, decompressed z): reused only when the blob matches
         self._zmemo: "OrderedDict[int, Tuple[bytes, np.ndarray]]" = \
             OrderedDict()
-        self._warm: set = set()       # buckets whose decode shape is compiled
+        # (bucket, latent shape) pairs whose decode is compiled
+        self._warm: set = set()
         # (bucket, latent shape) pairs this batcher has decoded, in first-
         # seen order — the kernel autotuner's tune-on-first-miss feed
         self._shape_log: List[Tuple[int, Tuple[int, ...]]] = []
@@ -229,19 +233,33 @@ class DecodeBatcher:
     def decode_single(self, z: np.ndarray) -> np.ndarray:
         """One-off decode of a single latent in the configured pixel
         format (prewarm / promotion paths outside the batched window)."""
-        return np.asarray(self._decode_fn(jnp.asarray(z, jnp.float32)[None]))[0]
+        zb = self.vae.place(np.asarray(z, np.float32)[None])
+        return np.asarray(self._decode_fn(zb))[0]
 
-    def prewarm(self, latent_hwc: Tuple[int, int, int]) -> None:
-        """Compile every bucket's decode shape up front so no serving
-        window ever pays jit time (first-flush warmup otherwise compiles
-        lazily, bucket by bucket).  With a tuning cache active, the trace
-        consults it — so prewarming compiles the *tuned* kernel shapes."""
+    def _warm_up(self, bucket: int, latent_hwc) -> float:
+        """Compile (bucket, latent shape) on a zeros batch unless it is
+        warm; returns the seconds spent (0.0 when already warm)."""
+        key = (int(bucket), tuple(int(v) for v in latent_hwc))
+        if key in self._warm:
+            return 0.0
+        t0 = time.perf_counter()
+        z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
+        np.asarray(self._decode_fn(z))
+        self._warm.add(key)
+        return time.perf_counter() - t0
+
+    def prewarm(self, latent_hwc: Tuple[int, int, int]) -> Dict[int, float]:
+        """Compile every bucket's decode for ``latent_hwc`` up front so no
+        serving window ever pays jit time (first-flush warmup otherwise
+        compiles lazily, bucket by bucket).  With a tuning cache active,
+        the trace consults it — so prewarming compiles the *tuned* kernel
+        shapes.  Returns bucket -> warm-up seconds (compile plus one
+        decode; 0.0 for a bucket that was already warm)."""
+        out = {}
         for b in self.buckets:
             self._note_shape(b, latent_hwc)
-            if b not in self._warm:
-                z = jnp.zeros((b,) + tuple(latent_hwc), jnp.float32)
-                np.asarray(self._decode_fn(z))
-                self._warm.add(b)
+            out[b] = self._warm_up(b, latent_hwc)
+        return out
 
     def _note_shape(self, bucket: int, latent_hwc) -> None:
         key = (int(bucket), tuple(int(v) for v in latent_hwc))
@@ -289,18 +307,12 @@ class DecodeBatcher:
         bucket = self.bucket_for(n_real)
         zs = [self._latent_of(oid, blob) for oid, (blob, _) in chunk]
         zs.extend([zs[-1]] * (bucket - n_real))       # pad with the last real z
-        zb = jnp.stack(zs)
+        zb = np.stack(zs)
         self._note_shape(bucket, zb.shape[1:])
-        if bucket not in self._warm:
-            # compile this bucket's shape outside the timed region so jit
-            # compile time never poisons the tuner's decode EWMA.  Warm on
-            # a THROWAWAY zeros buffer: the u8 decode donates its input,
-            # so warming on zb itself would delete the buffer the real
-            # decode still needs (CPU ignores donation, accelerators
-            # do not)
-            np.asarray(self._decode_fn(jnp.zeros(zb.shape, zb.dtype)))
-            self._warm.add(bucket)
-        return zb, bucket, n_real
+        # compile a new (bucket, latent shape) outside the timed region so
+        # jit compile time never poisons the tuner's decode EWMA
+        self._warm_up(bucket, zb.shape[1:])
+        return self.vae.place(zb), bucket, n_real
 
     def _account(self, chunk, imgs, per_image_ms, bucket, n_real):
         self.stats["batches"] += 1
@@ -461,10 +473,12 @@ class ServingEngine:
                 self.tuning_cache, vae.cfg,
                 weight_dtype=self.cfg.weight_dtype)
 
-    def prewarm_decode(self, latent_hwc: Tuple[int, int, int]) -> None:
+    def prewarm_decode(self, latent_hwc: Tuple[int, int, int]
+                       ) -> Dict[int, float]:
         """Compile every decode bucket for the given latent shape up
-        front, so no serving batch ever pays jit time."""
-        self.batcher.prewarm(latent_hwc)
+        front, so no serving batch ever pays jit time; returns bucket ->
+        warm-up seconds."""
+        return self.batcher.prewarm(latent_hwc)
 
     # -- writes ---------------------------------------------------------------
 
@@ -493,7 +507,7 @@ class ServingEngine:
             if img4.ndim == 3:
                 img4 = img4[None]
             latent = np.asarray(
-                self.vae.encode_mean(jnp.asarray(img4)))[0].astype(np.float16)
+                self.vae.encode_mean(img4))[0].astype(np.float16)
         blob = compress_latent(np.asarray(latent))
         self.store.put(oid, blob)
         self.batcher.forget(oid)            # durable blob rewritten
@@ -548,7 +562,7 @@ class ServingEngine:
         if recipe is None:
             raise KeyError(f"object {oid} has no recipe to regenerate from")
         z = np.asarray(self.vae.encode_mean(
-            jnp.asarray(synthesize_image(recipe))))[0].astype(np.float16)
+            synthesize_image(recipe)))[0].astype(np.float16)
         blob = compress_latent(z)
         self.store.put(oid, blob)
         self.batcher.forget(oid)            # durable blob rewritten

@@ -50,8 +50,8 @@ class LatentBox:
         cluster (see :class:`~repro.store.sharding.ShardedLatentBox`)."""
         from repro.store.backends import EngineBackend
         if vae is None:
-            from repro.vae.model import demo_vae
-            vae = demo_vae(seed=seed)
+            from repro.vae.model import calibrated_vae
+            vae = calibrated_vae("demo", seed=seed)
         if shards > 1 or (replication or 1) > 1 or fault_plan is not None:
             from repro.store.sharding import ShardedLatentBox
             return cls(ShardedLatentBox.engine(

@@ -122,6 +122,16 @@ class _Downed:
 _global_node_index = parse_node_index    # names are 'node<global idx>'
 
 
+def shard_device(node_names: Sequence[str], devices: Sequence[Any]) -> Any:
+    """The device a shard's engine decodes on.  Shards own consecutive
+    slices of the global node namespace, so the first node's index over
+    the slice width is the shard's slot; slots wrap around the devices.
+    A failover proxy carries its dead shard's names and lands on the same
+    device."""
+    slot = _global_node_index(node_names[0]) // len(node_names)
+    return devices[slot % len(devices)]
+
+
 class ShardedLatentBox:
     """Consistent-hash placement of objects over N per-shard backends.
 
@@ -314,12 +324,27 @@ class ShardedLatentBox:
                hedge: Optional[HedgeConfig] = None,
                fault_plan: Optional[FaultPlan] = None
                ) -> "ShardedLatentBox":
-        """All shards share one ``vae`` instance, so the jitted decode
-        compiles once per batch-bucket shape for the whole cluster."""
+        """Each shard decodes on its own device where the process has
+        several (:func:`shard_device`): the shard's decoder weights and
+        latent batches are committed there, so a four-shard box on a
+        four-chip host drives all four chips from one process.  With one
+        device every shard shares ``vae`` itself.  All views share the
+        jitted entry points (one executable per device and bucket)."""
+        import jax
         from repro.store.backends import EngineBackend
-        return cls(lambda cfg: EngineBackend(vae, cfg), n_shards, config,
-                   replication=replication, hedge=hedge,
-                   fault_plan=fault_plan)
+        devices = jax.local_devices()
+        views: Dict[Any, Any] = {}
+
+        def backend(cfg: StoreConfig):
+            if len(devices) == 1:
+                return EngineBackend(vae, cfg)
+            dev = shard_device(cfg.node_names, devices)
+            if dev not in views:
+                views[dev] = vae.to_device(dev)
+            return EngineBackend(views[dev], cfg)
+
+        return cls(backend, n_shards, config, replication=replication,
+                   hedge=hedge, fault_plan=fault_plan)
 
     # -- topology ------------------------------------------------------------
     @property

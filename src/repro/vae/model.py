@@ -10,7 +10,9 @@ pass: same latent -> bit-identical pixels on a fixed stack.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -51,15 +53,26 @@ DEMO_VAE = VAEConfig(name="demo", latent_channels=4,
                      groups=4)
 
 
-def demo_vae(seed: int = 0, impl: Optional[str] = None,
-             weight_dtype: str = "float32") -> "VAE":
-    """The demo :class:`VAE` with its output range calibrated into the
-    display domain (random-init decoders saturate the [-1, 1] clamp,
-    which no trained decoder does — and which would make quantization
-    gates and fidelity metrics unrepresentative).  Deterministic per
-    seed, so every open of the same stack decodes bit-identically."""
+#: Decoder configurations by name (the launcher's ``--decoder``).
+CONFIGS = {c.name: c for c in (SD35_VAE, FLUX_VAE, SD15_VAE, DEMO_VAE)}
+
+
+def calibrated_vae(name: str = "demo", seed: int = 0,
+                   impl: Optional[str] = None,
+                   weight_dtype: str = "float32") -> "VAE":
+    """The :class:`VAE` of config ``name`` (:data:`CONFIGS`) with seeded
+    random weights and its output range calibrated into the display
+    domain (random-init decoders saturate the [-1, 1] clamp, which no
+    trained decoder does — and which would make quantization gates and
+    fidelity metrics unrepresentative).  Deterministic per seed, so every
+    open of the same stack decodes bit-identically."""
     from repro.vae.quantize import calibrate_output_range
-    vae = VAE(DEMO_VAE, seed=seed, impl=impl)
+    try:
+        cfg = CONFIGS[name]
+    except KeyError:
+        raise ValueError(f"decoder must be one of {sorted(CONFIGS)}: "
+                         f"{name!r}") from None
+    vae = VAE(cfg, seed=seed, impl=impl)
     calibrate_output_range(vae)
     if weight_dtype != "float32":
         vae.set_weight_dtype(weight_dtype)
@@ -137,6 +150,18 @@ def init_encoder(key, cfg: VAEConfig) -> Dict[str, Any]:
 # forward passes
 # ---------------------------------------------------------------------------
 
+def _fp32_math(fn):
+    """Trace ``fn`` with fp32 matmul/conv precision.  On TPU the default
+    precision of an fp32 dot is one bf16 pass; the decode's XLA ops (1x1
+    shortcut convs, attention projections) would then drift from the fp32
+    oracle by more than the ±1-LSB contract.  A no-op on CPU."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 def _decode_trunk(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
                   impl: Optional[str] = None) -> jax.Array:
     """Shared decode trunk: latent -> pre-epilogue activation [N, 8h, 8w,
@@ -154,6 +179,7 @@ def _decode_trunk(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
     return x
 
 
+@_fp32_math
 def decode(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
            impl: Optional[str] = None) -> jax.Array:
     """latent [N, h, w, C_lat] -> image [N, 8h, 8w, 3] in [-1, 1]."""
@@ -162,6 +188,7 @@ def decode(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
     return L.conv2d(x, params["conv_out"], impl=impl)
 
 
+@_fp32_math
 def decode_u8(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
               impl: Optional[str] = None) -> jax.Array:
     """The uint8 regeneration fast path: latent [N, h, w, C_lat] ->
@@ -180,6 +207,7 @@ def decode_u8(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
         groups=cfg.groups, impl=impl)
 
 
+@_fp32_math
 def encode(params: Dict[str, Any], x: jax.Array, cfg: VAEConfig,
            impl: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """image [N, H, W, 3] -> (mean, logvar) latents [N, H/8, W/8, C_lat]."""
@@ -212,27 +240,31 @@ class VAE:
     as the oracle — :meth:`decode` and ``decode_u8(z,
     precision='float32')`` run it, which is what the engine's ±1-LSB
     open-time gate compares against.
+
+    ``device`` is where the weights are committed (``None``: JAX's
+    default device, uncommitted); :meth:`to_device` makes a view placed
+    on another device that shares this instance's jitted entry points.
     """
 
     def __init__(self, cfg: VAEConfig = SD35_VAE, seed: int = 0,
                  with_encoder: bool = True, impl: Optional[str] = None,
                  weight_dtype: str = "float32"):
         self.cfg = cfg
-        self.impl = impl          # None -> process default (ops.set_default_impl)
+        self.impl = impl          # None -> chosen from the platform (ops)
         key = jax.random.PRNGKey(seed)
         kd, ke = jax.random.split(key)
-        self.decoder = init_decoder(kd, cfg)
-        self.encoder = init_encoder(ke, cfg) if with_encoder else None
+        # one jitted init per tree: on an accelerator, eager init would
+        # compile every distinct parameter shape separately
+        self.decoder = jax.jit(init_decoder, static_argnums=1)(kd, cfg)
+        self.encoder = (jax.jit(init_encoder, static_argnums=1)(ke, cfg)
+                        if with_encoder else None)
         self.weight_dtype = "float32"
         self._qparams: Dict[str, Any] = {}
+        self.device = None
         self._decode = jax.jit(lambda p, z: decode(p, z, cfg, impl))
-        # the uint8 fast path donates the latent batch: the batcher stacks
-        # a fresh buffer per flush, so the compiled decode can reuse it
-        # in-place (donation is a no-op where the backend lacks support,
-        # e.g. CPU — gated to keep the run warning-free there)
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        self._decode_u8 = jax.jit(lambda p, z: decode_u8(p, z, cfg, impl),
-                                  donate_argnums=donate)
+        # no input donation: the uint8 output cannot alias the float32
+        # latent batch, so XLA reports a donated batch as unusable
+        self._decode_u8 = jax.jit(lambda p, z: decode_u8(p, z, cfg, impl))
         self._encode = jax.jit(lambda p, x: encode(p, x, cfg, impl))
         if weight_dtype != "float32":
             self.set_weight_dtype(weight_dtype)
@@ -258,19 +290,38 @@ class VAE:
                                                           precision)
         return self._qparams[precision]
 
+    def to_device(self, device) -> "VAE":
+        """A view of this VAE with every weight tree committed to
+        ``device``.  The jitted entry points are shared, so each decode
+        runs on the device its inputs live on (one executable per device
+        and shape)."""
+        out = copy.copy(self)
+        out.decoder = jax.device_put(self.decoder, device)
+        out.encoder = (None if self.encoder is None
+                       else jax.device_put(self.encoder, device))
+        out._qparams = {k: (out.decoder if k == "float32"
+                            else jax.device_put(v, device))
+                        for k, v in self._qparams.items()}
+        out.device = device
+        return out
+
+    def place(self, x) -> jax.Array:
+        """Host or device array -> device array where the weights live."""
+        return jax.device_put(x, self.device)
+
     def decode(self, z: jax.Array) -> jax.Array:
         """Float pixels off the fp32 oracle weights (quantization only
         ever applies to the uint8 serving path)."""
-        return self._decode(self.decoder, z)
+        return self._decode(self.decoder, self.place(z))
 
     def decode_u8(self, z: jax.Array,
                   precision: Optional[str] = None) -> jax.Array:
-        """Donated end-to-end fast path: latents -> uint8 HWC pixels.
+        """End-to-end fast path: latents -> uint8 HWC pixels.
 
         ``precision`` overrides the configured ``weight_dtype`` for this
         call ('float32' forces the oracle weights — the gate's reference
         arm); default serves the configured storage precision."""
-        return self._decode_u8(self._params_for(precision), z)
+        return self._decode_u8(self._params_for(precision), self.place(z))
 
     def refresh_kernels(self) -> None:
         """Drop compiled decode/encode executables so the next call
@@ -283,7 +334,7 @@ class VAE:
                 clear()
 
     def encode_mean(self, x: jax.Array) -> jax.Array:
-        return self._encode(self.encoder, x)[0]
+        return self._encode(self.encoder, self.place(x))[0]
 
     @property
     def decoder_params(self) -> int:

@@ -138,8 +138,7 @@ def gate_max_lsb(vae, buckets: Sequence[int],
                  latent_hwc: Tuple[int, int, int],
                  seed: int = 0) -> Dict[int, int]:
     """Per-bucket max |uint8 difference| between the quantized and the
-    f32-oracle ``decode_u8`` on shared probe latents.  Fresh device
-    arrays per call — ``decode_u8`` donates its input buffer."""
+    f32-oracle ``decode_u8`` on shared probe latents."""
     out: Dict[int, int] = {}
     for b in sorted(set(int(x) for x in buckets)):
         z = probe_latents(latent_hwc, b, seed)

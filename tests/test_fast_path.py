@@ -94,12 +94,42 @@ class TestPipelinedFlush:
                                           results[True][oid])
 
     def test_prewarm_compiles_all_buckets(self, vae, store):
+        want = {(bk, LATENT_HWC) for bk in (1, 2, 4, 8)}
         b = DecodeBatcher(vae, (1, 2, 4, 8))
-        b.prewarm(LATENT_HWC)
-        assert b._warm == {1, 2, 4, 8}
+        secs = b.prewarm(LATENT_HWC)
+        assert b._warm == want
+        assert set(secs) == {1, 2, 4, 8}
+        assert b.prewarm(LATENT_HWC) == {1: 0.0, 2: 0.0, 4: 0.0, 8: 0.0}
         eng = make_engine(vae, store)
         eng.prewarm_decode(LATENT_HWC)
-        assert eng.batcher._warm == {1, 2, 4, 8}
+        assert eng.batcher._warm == want
+
+    def test_new_latent_shape_warms_before_it_is_timed(self, vae):
+        """A batcher warmed for one latent shape meets a second shape: the
+        compile lands in warm-up, outside the timed decode that feeds the
+        tuner's EWMA."""
+        node = types.SimpleNamespace(tuner=None)
+        b = DecodeBatcher(vae, (1, 2))
+        b.prewarm(LATENT_HWC)
+        other = (4, 4, LATENT_HWC[2])
+        calls = []
+        decode = b._decode_fn
+
+        def spy(zb):
+            calls.append(((int(zb.shape[0]), tuple(zb.shape[1:])),
+                          set(b._warm)))
+            return decode(zb)
+
+        b._decode_fn = spy
+        rng = np.random.default_rng(0)
+        b.submit(0, compress_latent(
+            rng.standard_normal(other).astype(np.float16)), node)
+        b.flush()
+        assert (1, other) in b._warm
+        # first call is the warm-up (shape not yet warm); the timed decode
+        # that follows already finds it warm
+        assert calls[0] == ((1, other), {(bk, LATENT_HWC) for bk in (1, 2)})
+        assert calls[1][0] == (1, other) and (1, other) in calls[1][1]
 
 
 class TestDecompressionMemo:

@@ -63,13 +63,18 @@ def test_gn_silu_conv3x3(n, h, w, cin, cout, groups):
 
 def test_band_rows_divides_h_under_vmem_pressure():
     """The VMEM halving must land on a divisor of h (e.g. h=18 would
-    otherwise shrink 18 -> 9 -> 4, and 4 does not divide 18)."""
-    from repro.kernels.conv3x3 import VMEM_BUDGET, band_rows
+    otherwise shrink 18 -> 9 -> 4, and 4 does not divide 18), and the
+    whole per-step footprint — double-buffered band, normalized-band
+    scratch, weights, output block — must fit the budget."""
+    from repro.kernels.conv3x3 import (VMEM_BUDGET, band_rows,
+                                       step_vmem_bytes)
     for h in (18, 24, 7, 5, 96):
         for width, cin in ((1024, 384), (16, 8), (4096, 512)):
-            r = band_rows(h, width, cin, 4, 32)
-            assert h % r == 0
-            assert r == 1 or (r + 2) * (width + 2) * cin * 4 <= VMEM_BUDGET
+            for fused_gn in (False, True):
+                r = band_rows(h, width, cin, 4, 32, fused_gn=fused_gn)
+                assert h % r == 0
+                assert r == 1 or step_vmem_bytes(
+                    r, width, cin, 4, fused_gn=fused_gn) <= VMEM_BUDGET
 
 
 def test_conv3x3_non_power_of_two_height_vmem_fallback():

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from repro.kernels import ops
 from repro.store import LatentBox, StoreConfig
 from repro.vae import quantize as Q
-from repro.vae.model import VAE, DEMO_VAE, demo_vae
+from repro.vae.model import VAE, DEMO_VAE, calibrated_vae
 
 LATENT_HWC = (8, 8, 4)
 BUCKETS = (1, 2, 4, 8)
@@ -20,12 +20,12 @@ BUCKETS = (1, 2, 4, 8)
 
 @pytest.fixture(scope="module")
 def vae_bf16():
-    return demo_vae(seed=0, weight_dtype="bfloat16")
+    return calibrated_vae("demo", seed=0, weight_dtype="bfloat16")
 
 
 @pytest.fixture(scope="module")
 def vae_int8_snapped():
-    vae = demo_vae(seed=0)
+    vae = calibrated_vae("demo", seed=0)
     Q.snap_to_grid(vae)
     vae.set_weight_dtype("int8")
     return vae
@@ -103,7 +103,7 @@ class TestGate:
     def test_raw_int8_random_decoder_rejected(self):
         """Unsnapped int8 on this decoder drifts past 1 LSB — the gate's
         whole point is that it, not a promise, decides admissibility."""
-        vae = demo_vae(seed=0)
+        vae = calibrated_vae("demo", seed=0)
         vae.set_weight_dtype("int8")
         with pytest.raises(Q.QuantizationGateError, match="int8"):
             Q.check_u8_gate(vae, (1, 2), LATENT_HWC)
@@ -167,14 +167,14 @@ class TestEngineGate:
             lambda params: Q._map_weights(
                 params, lambda p: (p * 0 if getattr(p, "ndim", 0) >= 2
                                    else p)))
-        vae = demo_vae(seed=0)
+        vae = calibrated_vae("demo", seed=0)
         with pytest.raises(Q.QuantizationGateError):
             LatentBox.engine(vae=vae,
                              config=store_config(weight_dtype="bfloat16",
                                                  decode_buckets=(1, 2)))
 
     def test_raw_int8_rejected_at_open(self):
-        vae = demo_vae(seed=0)
+        vae = calibrated_vae("demo", seed=0)
         with pytest.raises(Q.QuantizationGateError):
             LatentBox.engine(vae=vae,
                              config=store_config(weight_dtype="int8",
