@@ -1,0 +1,2 @@
+"""conv_roofline.scan: see lib/readers.py (conv_roofline)."""
+from lib.readers import conv_roofline as read  # noqa: F401
