@@ -32,6 +32,16 @@ a real image's pixels, but batches of different size are different
 compiled programs, so a bucketed decode agrees with a batch-1 decode of
 the same latent to the uint8 contract of the whole fast path — within
 ±1 LSB — not bit for bit.
+
+The read path records ``lb.*`` spans (``jax.profiler.TraceAnnotation``)
+where its work happens: ``lb.serve_window`` around a call, ``lb.lookup``
+per request (``lb.fetch`` for its durable read), ``lb.flush`` around the
+batched decode with ``lb.assemble`` (``lb.decompress``, ``lb.warm_up``,
+``lb.place``), ``lb.dispatch`` and ``lb.collect`` per chunk, and
+``lb.writeback`` after it.  Spans of one call share the ``call`` stat,
+those of one request its ``oid``.  They land in a profiler trace, on the
+device's clock, only while one is being recorded
+(``jax.profiler.start_trace``); otherwise each costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -43,6 +53,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.compression.latentcodec import compress_latent, decompress_latent
 from repro.core.dual_cache import IMAGE_HIT, LATENT_HIT
@@ -230,6 +241,19 @@ class DecodeBatcher:
             return self.vae.decode_u8(zb)
         return self.vae.decode(zb)
 
+    def _dispatch(self, zb, bucket: int):
+        """Enqueue one chunk's decode (asynchronous)."""
+        with TraceAnnotation("lb.dispatch", bucket=bucket):
+            return self._decode_fn(zb)
+
+    @staticmethod
+    def _await(fut, bucket: int) -> np.ndarray:
+        """Wait for a dispatched decode and copy its pixels to the host."""
+        with TraceAnnotation("lb.collect", bucket=bucket) as span:
+            imgs = np.asarray(fut)                    # blocks until done
+            span.set_metadata(bytes=imgs.nbytes)
+        return imgs
+
     def decode_single(self, z: np.ndarray) -> np.ndarray:
         """One-off decode of a single latent in the configured pixel
         format (prewarm / promotion paths outside the batched window)."""
@@ -242,11 +266,12 @@ class DecodeBatcher:
         key = (int(bucket), tuple(int(v) for v in latent_hwc))
         if key in self._warm:
             return 0.0
-        t0 = time.perf_counter()
-        z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
-        np.asarray(self._decode_fn(z))
-        self._warm.add(key)
-        return time.perf_counter() - t0
+        with TraceAnnotation("lb.warm_up", bucket=key[0]):
+            t0 = time.perf_counter()
+            z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
+            np.asarray(self._decode_fn(z))
+            self._warm.add(key)
+            return time.perf_counter() - t0
 
     def prewarm(self, latent_hwc: Tuple[int, int, int]) -> Dict[int, float]:
         """Compile every bucket's decode for ``latent_hwc`` up front so no
@@ -292,7 +317,8 @@ class DecodeBatcher:
             self.stats["memo_hits"] += 1
             return hit[1]
         self.stats["decompressions"] += 1
-        z = np.asarray(decompress_latent(blob), np.float32)
+        with TraceAnnotation("lb.decompress", oid=oid, bytes=len(blob)):
+            z = np.asarray(decompress_latent(blob), np.float32)
         if self.memo_entries > 0:
             self._zmemo[oid] = (blob, z)
             self._zmemo.move_to_end(oid)
@@ -305,14 +331,16 @@ class DecodeBatcher:
         bucket, stack, and make sure the bucket's shape is compiled."""
         n_real = len(chunk)
         bucket = self.bucket_for(n_real)
-        zs = [self._latent_of(oid, blob) for oid, (blob, _) in chunk]
-        zs.extend([zs[-1]] * (bucket - n_real))       # pad with the last real z
-        zb = np.stack(zs)
-        self._note_shape(bucket, zb.shape[1:])
-        # compile a new (bucket, latent shape) outside the timed region so
-        # jit compile time never poisons the tuner's decode EWMA
-        self._warm_up(bucket, zb.shape[1:])
-        return self.vae.place(zb), bucket, n_real
+        with TraceAnnotation("lb.assemble", bucket=bucket, n_real=n_real):
+            zs = [self._latent_of(oid, blob) for oid, (blob, _) in chunk]
+            zs.extend([zs[-1]] * (bucket - n_real))   # pad: last real z
+            zb = np.stack(zs)
+            self._note_shape(bucket, zb.shape[1:])
+            # compile a new (bucket, latent shape) outside the timed region
+            # so jit compile time never poisons the tuner's decode EWMA
+            self._warm_up(bucket, zb.shape[1:])
+            with TraceAnnotation("lb.place", bytes=zb.nbytes):
+                return self.vae.place(zb), bucket, n_real
 
     def _account(self, chunk, imgs, per_image_ms, bucket, n_real):
         self.stats["batches"] += 1
@@ -344,7 +372,7 @@ class DecodeBatcher:
             for chunk in chunks:
                 zb, bucket, n_real = self._assemble(chunk)
                 t0 = time.perf_counter()
-                imgs = np.asarray(self._decode_fn(zb))
+                imgs = self._await(self._dispatch(zb, bucket), bucket)
                 ms = (time.perf_counter() - t0) * 1e3
                 results.update(self._account(chunk, imgs, ms / n_real,
                                              bucket, n_real))
@@ -355,7 +383,7 @@ class DecodeBatcher:
         for chunk in chunks:
             zb, bucket, n_real = self._assemble(chunk)
             t0 = time.perf_counter()
-            fut = self._decode_fn(zb)                 # async dispatch
+            fut = self._dispatch(zb, bucket)
             if inflight is not None:
                 prev_done = self._collect(results, *inflight)
             # the device runs chunks serially: this chunk only starts once
@@ -366,7 +394,7 @@ class DecodeBatcher:
         return results
 
     def _collect(self, results, chunk, fut, start, bucket, n_real) -> float:
-        imgs = np.asarray(fut)                        # blocks until done
+        imgs = self._await(fut, bucket)
         done = time.perf_counter()
         per_image_ms = (done - start) * 1e3 / n_real
         results.update(self._account(chunk, imgs, per_image_ms, bucket,
@@ -384,7 +412,9 @@ class _Ticket:
     img: Optional[np.ndarray] = None          # set on image hit
     write_image: bool = False                 # promote/pin decision at lookup
     spilled: bool = False
-    fetch_ms: float = 0.0                     # measured durable-fetch wall
+    #: measured durable-fetch wall plus the store's modelled latency draw
+    #: (``store.fetch_ms``); the tuner's fetch EWMA reads it
+    fetch_ms: float = 0.0
     regen_ms: float = 0.0                     # measured regeneration wall
     decode_ms: float = 0.0                    # per-image share of its batch
 
@@ -423,6 +453,7 @@ class ServingEngine:
                                      pixel_format=self.cfg.pixel_format)
         self.stats = self.walk.counts           # shared hit/spill accounting
         self._inflight: List[_Ticket] = []      # open microbatch (admit/dispatch)
+        self._calls = 0                         # serve_window calls
         # -- quantized decoder (gated) + persistent kernel autotuner ---------
         self.gate_lsb: Optional[Dict[int, int]] = None
         if self.cfg.weight_dtype != "float32":
@@ -573,8 +604,16 @@ class ServingEngine:
 
     def _lookup(self, oid: int) -> _Ticket:
         """Route one request up to (but excluding) the decode: the shared
-        tier-walk classifies and admits; this method materializes payloads
-        (durable fetch / regeneration) and enqueues the decode."""
+        tier-walk classifies and admits; :meth:`_route` materializes
+        payloads (durable fetch / regeneration) and enqueues the decode.
+        The span records the hit class and whether the pixels were already
+        in hand (``ready``)."""
+        with TraceAnnotation("lb.lookup", call=self._calls, oid=oid) as span:
+            t = self._route(oid)
+            span.set_metadata(cls=t.outcome, ready=int(t.img is not None))
+        return t
+
+    def _route(self, oid: int) -> _Ticket:
         ticket = self.walk.lookup(
             oid, depth_of=lambda i: self.nodes[i].queue_depth)
         owner = self.nodes[ticket.owner]
@@ -612,7 +651,9 @@ class ServingEngine:
                 owner.latents[oid] = blob
         else:                                         # durable fetch
             t0 = time.perf_counter()
-            blob = self.store.get(oid)
+            with TraceAnnotation("lb.fetch", oid=oid) as span:
+                blob = self.store.get(oid)
+                span.set_metadata(bytes=len(blob or b""))
             if blob is None:
                 raise KeyError(f"object {oid} has no durable payload "
                                "(size-only registration?)")
@@ -666,24 +707,25 @@ class ServingEngine:
         maintenance.  Returns the admitted tickets in admission order."""
         tickets, self._inflight = self._inflight, []
         decoded = self._flush()
-        touched = {}
-        for t in tickets:
-            if t.img is not None:
-                continue
-            img = decoded[t.oid]
-            t.decode_ms = self.batcher.last_per_image_ms.get(t.oid, 0.0)
-            # cache pinning: decoded result written back to the OWNER node
-            if t.write_image or t.owner.cache.contains(t.oid) == "image":
-                t.owner.images[t.oid] = img
-                # charge the pixel tier the stored array's real bytes
-                # (uint8 on the fast path) — a size-only correction, so
-                # the LRU order stays identical to the simulator's
-                t.owner.cache.set_image_nbytes(t.oid, img.nbytes)
-            touched[id(t.owner)] = t.owner
-            t.img = img
-        for node in touched.values():
-            self._gc(node)
-        self._durable_maintenance()
+        with TraceAnnotation("lb.writeback", call=self._calls):
+            touched = {}
+            for t in tickets:
+                if t.img is not None:
+                    continue
+                img = decoded[t.oid]
+                t.decode_ms = self.batcher.last_per_image_ms.get(t.oid, 0.0)
+                # cache pinning: decoded result written back to the OWNER
+                if t.write_image or t.owner.cache.contains(t.oid) == "image":
+                    t.owner.images[t.oid] = img
+                    # charge the pixel tier the stored array's real bytes
+                    # (uint8 on the fast path) — a size-only correction, so
+                    # the LRU order stays identical to the simulator's
+                    t.owner.cache.set_image_nbytes(t.oid, img.nbytes)
+                touched[id(t.owner)] = t.owner
+                t.img = img
+            for node in touched.values():
+                self._gc(node)
+            self._durable_maintenance()
         return tickets
 
     def _abort_open_batch(self) -> None:
@@ -703,9 +745,12 @@ class ServingEngine:
         ``GetResult``.  The serving runtime's drain-mode conformance
         guarantee is defined against this path.
         """
-        for oid in oids:
-            self.admit(oid)
-        return self.dispatch()
+        self._calls += 1
+        with TraceAnnotation("lb.serve_window", call=self._calls,
+                             n=len(oids)):
+            for oid in oids:
+                self.admit(oid)
+            return self.dispatch()
 
     def serve_stream(self, requests, runtime_cfg=None):
         """Replay an open-loop request stream through the event-loop
@@ -786,11 +831,14 @@ class ServingEngine:
             self.walk.set_cache_capacity(self._cache_bytes_per_node)
 
     def _flush(self) -> Dict[int, np.ndarray]:
-        try:
-            return self.batcher.flush()
-        finally:
-            for n in self.nodes:
-                n.queue_depth = 0               # all in-flight decodes drained
+        n = len(self.batcher)
+        with TraceAnnotation("lb.flush", call=self._calls, decodes=n,
+                             chunks=-(-n // self.batcher.max_batch)):
+            try:
+                return self.batcher.flush()
+            finally:
+                for node in self.nodes:
+                    node.queue_depth = 0        # all in-flight decodes drained
 
     def _gc(self, node: _Node) -> None:
         if len(node.images) > 2 * len(node.cache.image_tier) + 32:
