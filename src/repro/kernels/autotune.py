@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.conv3x3 import band_rows
+from repro.kernels.conv3x3 import band_rows, cout_tile, plan
 
 SCHEMA_VERSION = 1
 CACHE_FILENAME = "tuning_cache.json"
@@ -231,16 +231,12 @@ def _effective(kernel: str, spec: Dict[str, Any], rows: int,
     mirrors the wrappers' clamping, so candidates that collapse to the
     same blocking are swept once."""
     h, w, cin, cout = spec["h"], spec["w"], spec["cin"], spec["cout"]
-    tc = min(block_cout, cout)
-    while cout % tc:
-        tc //= 2
-    if kernel == "upsample_conv3x3":
-        r = band_rows(h, w, cin, itemsize, rows, tc=tc, taps=8,
-                      out_factor=2)
-    else:
-        r = band_rows(h, w, cin, itemsize, rows, tc=tc,
-                      fused_gn=kernel != "conv3x3")
-    return r, tc
+    if kernel != "upsample_conv3x3":
+        return plan((1, h, w, cin), cout, itemsize, rows, block_cout,
+                    kernel != "conv3x3")[:2]
+    tc = cout_tile(cout, block_cout)
+    return band_rows(h, w, cin, itemsize, rows, tc=tc, taps=8,
+                     out_factor=2), tc
 
 
 def candidates(kernel: str, spec: Dict[str, Any],

@@ -1,11 +1,20 @@
 """3x3 SAME conv as implicit GEMM — the decode path's dominant FLOP source.
 
 TPU-native formulation (not an im2col port): for each output row-band the
-kernel holds an input band + 1-row halo in VMEM and, one output row at a
-time, accumulates nine (W, Cin) x (Cin, Cout-tile) MXU matmuls — one per
-filter tap — shifted in the spatial dims.  Channels stay on the lane axis;
+kernel holds an input band + 1-row halo in VMEM and accumulates shifted
+(W, Cin) x (Cin, Cout-tile) MXU matmuls.  Channels stay on the lane axis;
 Cout tiles are 128-aligned for the MXU, and every bias/scale operand is a
 lane-aligned ``(1, C)`` row.
+
+A band of an even number of rows runs Winograd minimal filtering F(2,3)
+along the row axis: each pair of output rows takes four transformed input
+rows (``d0-d2, d1+d2, d2-d1, d1-d3``) times three column taps of the
+row-transformed filter (:func:`winograd_weights`), 12 matmuls where the
+direct loop takes 2 x 9 = 18.  The transforms use only +-1 and 1/2 and the
+products stay fp32 at ``Precision.HIGHEST``, so the result differs from
+the direct sum by fp32 rounding alone.  A band of odd height (one row,
+where two do not fit VMEM) keeps the direct nine-tap loop.  The column
+taps stay unaligned ``pl.ds(dx, W)`` loads on either path.
 
 Overlapping halo reads don't fit disjoint BlockSpec tiling, so the wrapper
 materializes the row bands (with halo) once in HBM — an extra 2/rows of
@@ -23,7 +32,7 @@ footprint (:func:`step_vmem_bytes`), and the kernel asks Mosaic for
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +95,39 @@ def cout_tile(cout: int, block_cout: int) -> int:
     return tc
 
 
+#: Filter taps per output-channel tile in VMEM: F(2,3) along rows holds a
+#: ``[4, 3, Cin, tc]`` transformed filter, the direct loop ``[3, 3, ...]``.
+WINOGRAD_TAPS = 12
+
+#: Banded convs traced on each path since the process started, counted
+#: per call site by the kernel dispatch (:mod:`repro.kernels.ops`; the
+#: engine reports the deltas of a decode's warm-up on its ``lb.warm_up``
+#: span).
+PATHS: Dict[str, int] = {"winograd_rows": 0, "direct": 0}
+
+
+def plan(x_shape: Tuple[int, ...], cout: int, itemsize: int, rows: int,
+         block_cout: int, fused_gn: bool) -> Tuple[int, int, str]:
+    """(band rows, Cout tile, path) of one banded conv of ``x_shape``
+    ``[N, H, W, Cin]``: an even band takes F(2,3) along rows
+    (``'winograd_rows'``), an odd one the nine-tap loop (``'direct'``)."""
+    _, h, width, cin = x_shape
+    tc = cout_tile(cout, block_cout)
+    rows = band_rows(h, width, cin, itemsize, rows, tc=tc,
+                     taps=WINOGRAD_TAPS, fused_gn=fused_gn)
+    return rows, tc, "winograd_rows" if rows % 2 == 0 else "direct"
+
+
+def winograd_weights(w: jax.Array) -> jax.Array:
+    """``[3, 3, Cin, Cout]`` filter -> the ``[4, 3, Cin, Cout]`` F(2,3)
+    row-transformed filter ``(w0, (w0+w1+w2)/2, (w0-w1+w2)/2, w2)`` over
+    ``dy``, in fp32 (int8 taps in their integer units: the per-channel
+    dequant scale still applies to the accumulator)."""
+    w = w.astype(jnp.float32)
+    return jnp.stack([w[0], (w[0] + w[1] + w[2]) * 0.5,
+                      (w[0] - w[1] + w[2]) * 0.5, w[2]])
+
+
 def materialize_bands(x: jax.Array, rows: int) -> jax.Array:
     """[N, H, W, C] -> flattened row bands with 1-pixel halo
     [N * H/rows, rows+2, W+2, C] (the overlapping halo reads don't fit
@@ -115,12 +157,12 @@ def quantize_u8(y: jax.Array) -> jax.Array:
 
 
 def _conv_kernel(*refs, rows: int, width: int, nb: int, fused_gn: bool,
-                 has_scale: bool, quantize: bool):
+                 has_scale: bool, quantize: bool, winograd: bool):
     it = iter(refs)
     x_ref = next(it)                                 # [1, rows+2, W+2, Cin]
     if fused_gn:
         mean_ref, mul_ref, gbias_ref = next(it), next(it), next(it)
-    w_ref = next(it)                                 # [3, 3, Cin, tc]
+    w_ref = next(it)                     # [4, 3, Cin, tc] or [3, 3, Cin, tc]
     s_ref = next(it) if has_scale else None          # [1, tc]
     b_ref, o_ref = next(it), next(it)                # [1, tc], [1, rows, W, tc]
 
@@ -160,21 +202,41 @@ def _conv_kernel(*refs, rows: int, width: int, nb: int, fused_gn: bool,
     bias = b_ref[...].astype(jnp.float32)
     scale = None if s_ref is None else s_ref[...].astype(jnp.float32)
 
+    def store(r, acc):
+        if scale is not None:
+            # scale is per output channel, so one fp32 multiply of the
+            # summed accumulator dequantizes every tap exactly
+            acc = acc * scale
+        y = acc + bias
+        o_ref[0, r] = quantize_u8(y) if quantize else y.astype(o_ref.dtype)
+
     def conv_row(r, carry):
         acc = None
         for dy in range(3):
             for dx in range(3):
                 d = tap_dot(load(r + dy, dx), w_ref[dy, dx])
                 acc = d if acc is None else acc + d
-        if scale is not None:
-            # scale is per output channel, so one fp32 multiply of the
-            # summed accumulator dequantizes all nine taps exactly
-            acc = acc * scale
-        y = acc + bias
-        o_ref[0, r] = quantize_u8(y) if quantize else y.astype(o_ref.dtype)
+        store(r, acc)
         return carry
 
-    jax.lax.fori_loop(0, rows, conv_row, 0)
+    def conv_pair(p, carry):
+        # F(2,3) along rows: output rows r, r+1 from band rows r..r+3
+        r = 2 * p
+        m = [None] * 4
+        for dx in range(3):
+            d0, d1, d2, d3 = (load(r + i, dx).astype(jnp.float32)
+                              for i in range(4))
+            for k, t in enumerate((d0 - d2, d1 + d2, d2 - d1, d1 - d3)):
+                d = tap_dot(t, w_ref[k, dx])
+                m[k] = d if m[k] is None else m[k] + d
+        store(r, m[0] + m[1] + m[2])
+        store(r + 1, m[1] - m[2] - m[3])
+        return carry
+
+    if winograd:
+        jax.lax.fori_loop(0, rows // 2, conv_pair, 0)
+    else:
+        jax.lax.fori_loop(0, rows, conv_row, 0)
 
 
 def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
@@ -187,14 +249,17 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
     ``gn = (mean_c, mul_c, bias_c)`` normalizes + SiLU-activates the band
     in VMEM first (per-image ``[N, 1, Cin]`` mean and ``inv_std * scale``
     from :func:`repro.kernels.gn_silu.gn_coefficients`, ``[Cin]`` bias);
-    ``quantize`` emits uint8 display pixels instead of ``x.dtype``."""
+    ``quantize`` emits uint8 display pixels instead of ``x.dtype``.
+    The path follows the band's height (:func:`plan`)."""
     n, h, width, cin = x.shape
     cout = w.shape[-1]
     if b is None:
         b = jnp.zeros((cout,), x.dtype)
-    tc = cout_tile(cout, block_cout)
-    rows = band_rows(h, width, cin, x.dtype.itemsize, rows, tc=tc,
-                     fused_gn=gn is not None)
+    rows, tc, path = plan(x.shape, cout, x.dtype.itemsize, rows, block_cout,
+                          gn is not None)
+    winograd = path == "winograd_rows"
+    if winograd:
+        w = winograd_weights(w)                      # [4, 3, Cin, Cout]
     nb = h // rows
 
     in_specs = [pl.BlockSpec((1, rows + 2, width + 2, cin),
@@ -208,7 +273,8 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
                      pl.BlockSpec((1, cin), lambda i, c: (0, 0))]
         operands += [mean_c, mul_c, gbias.reshape(1, cin)]
         scratch.append(pltpu.VMEM((rows + 2, width + 2, cin), jnp.float32))
-    in_specs.append(pl.BlockSpec((3, 3, cin, tc), lambda i, c: (0, 0, 0, c)))
+    in_specs.append(pl.BlockSpec((w.shape[0], 3, cin, tc),
+                                 lambda i, c: (0, 0, 0, c)))
     operands.append(w)
     if w_scale is not None:
         in_specs.append(pl.BlockSpec((1, tc), lambda i, c: (0, c)))
@@ -219,7 +285,8 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
     out = pl.pallas_call(
         functools.partial(_conv_kernel, rows=rows, width=width, nb=nb,
                           fused_gn=gn is not None,
-                          has_scale=w_scale is not None, quantize=quantize),
+                          has_scale=w_scale is not None, quantize=quantize,
+                          winograd=winograd),
         grid=(n * nb, cout // tc),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, width, tc),
@@ -241,8 +308,10 @@ def conv3x3(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
             w_scale: Optional[jax.Array] = None) -> jax.Array:
     """x [N, H, W, Cin], w [3, 3, Cin, Cout] -> [N, H, W, Cout] (SAME).
 
-    ``w`` may be stored float32/bfloat16 (cast to fp32 per tap tile) or
-    int8 with ``w_scale`` [Cout] — the per-channel dequant then happens on
-    the accumulator in VMEM, never as an fp32 weight copy in HBM."""
+    ``w`` may be stored float32/bfloat16 or int8 with ``w_scale`` [Cout]
+    — the per-channel dequant then happens on the accumulator in VMEM.  An
+    even band takes the fp32 row-transformed filter
+    (:func:`winograd_weights`, int8 taps in integer units); an odd band
+    casts each tap tile to fp32 in VMEM."""
     return banded_conv(x, w, b, rows=rows, block_cout=block_cout,
                        interpret=interpret, w_scale=w_scale)

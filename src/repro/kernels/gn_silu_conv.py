@@ -4,7 +4,7 @@ Every res block in the VAE decoder is ``conv3x3(silu(gn(x)))``; unfused,
 the normalized activation makes a full HBM round-trip between the GN+SiLU
 kernel and the conv.  This kernel keeps it in VMEM: the input row band
 (with 1-row halo) is normalized, activated, and immediately consumed by
-the nine implicit-GEMM filter-tap matmuls, eliminating one read + one
+the implicit-GEMM filter-tap matmuls, eliminating one read + one
 write of the [H, W, C] activation per block — the decoder's dominant
 memory term (see the roofline in :mod:`repro.vae.serve` and the traffic
 rows in ``benchmarks/bench_kernels.py``).
@@ -16,9 +16,10 @@ Structure (GN stats must exist before the conv can run):
   pass 2  grid (N*nb, Cout/tc): per row-band, normalize + SiLU the band
           into a VMEM scratch — including its halo rows, which are real
           neighbor pixels, and re-zeroing the conv's SAME padding ring
-          (``silu(gn(0)) != 0``) — then accumulate the nine shifted
-          (W, Cin) x (Cin, tc) MXU matmuls per output row exactly as
-          :mod:`repro.kernels.conv3x3` does (:func:`banded_conv`).
+          (``silu(gn(0)) != 0``) — then accumulate the shifted
+          (W, Cin) x (Cin, tc) MXU matmuls exactly as
+          :mod:`repro.kernels.conv3x3` does (:func:`banded_conv`: F(2,3)
+          along rows for an even band, nine taps per row for an odd one).
 """
 
 from __future__ import annotations

@@ -130,6 +130,19 @@ def _weight_parts(w):
     return w, None
 
 
+def _note_path(kernel: str, x, wq, tuned) -> None:
+    """Count the traced call site of a banded conv in
+    :data:`repro.kernels.conv3x3.PATHS` by the path its band takes.  Here,
+    at trace time of the caller: inside the jitted wrappers a Python side
+    effect would run once per distinct shape, since JAX reuses an inner
+    ``jit``'s trace, not once per conv of the decode."""
+    from repro.kernels import conv3x3 as c3
+    knobs = tuned or autotune.DEFAULTS[kernel]
+    path = c3.plan(x.shape, wq.shape[-1], x.dtype.itemsize, knobs["rows"],
+                   knobs["block_cout"], kernel != "conv3x3")[2]
+    c3.PATHS[path] += 1
+
+
 def _dequant(w, dtype=jnp.float32):
     if isinstance(w, QuantizedWeight):
         return w.dequant(dtype)
@@ -159,6 +172,7 @@ def gn_silu_conv3x3(x, scale, bias, w, b=None, groups: int = 32,
     wq, w_scale = _weight_parts(w)
     tuned = autotune.tuned_params("gn_silu_conv3x3", x.shape, wq.shape[-1],
                                   weight_dtype_of(w))
+    _note_path("gn_silu_conv3x3", x, wq, tuned)
     return gsc.gn_silu_conv3x3(x, scale, bias, wq, b, groups=groups, eps=eps,
                                w_scale=w_scale,
                                interpret=impl == "pallas_interpret", **tuned)
@@ -190,6 +204,7 @@ def output_epilogue(x, scale, bias, w, b=None, groups: int = 32,
     wq, w_scale = _weight_parts(w)
     tuned = autotune.tuned_params("output_epilogue", x.shape, wq.shape[-1],
                                   weight_dtype_of(w))
+    _note_path("output_epilogue", x, wq, tuned)
     return oe.output_epilogue(x, scale, bias, wq, b, groups=groups, eps=eps,
                               w_scale=w_scale,
                               interpret=impl == "pallas_interpret", **tuned)
@@ -225,6 +240,7 @@ def conv3x3(x, w, b=None, impl: Optional[str] = None):
     wq, w_scale = _weight_parts(w)
     tuned = autotune.tuned_params("conv3x3", x.shape, wq.shape[-1],
                                   weight_dtype_of(w))
+    _note_path("conv3x3", x, wq, tuned)
     return c3.conv3x3(x, wq, b, w_scale=w_scale,
                       interpret=impl == "pallas_interpret", **tuned)
 

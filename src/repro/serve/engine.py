@@ -36,7 +36,8 @@ the same latent to the uint8 contract of the whole fast path — within
 The read path records ``lb.*`` spans (``jax.profiler.TraceAnnotation``)
 where its work happens: ``lb.serve_window`` around a call, ``lb.lookup``
 per request (``lb.fetch`` for its durable read), ``lb.flush`` around the
-batched decode with ``lb.assemble`` (``lb.decompress``, ``lb.warm_up``,
+batched decode with ``lb.assemble`` (``lb.decompress``, ``lb.warm_up``
+with the banded convs its compile traced per kernel path,
 ``lb.place``), ``lb.dispatch`` and ``lb.collect`` per chunk, and
 ``lb.writeback`` after it.  Spans of one call share the ``call`` stat,
 those of one request its ``oid``.  They land in a profiler trace, on the
@@ -61,6 +62,7 @@ from repro.core.latent_store import LatentStore
 from repro.core.regen_tier import Recipe, RegenTierStore, synthesize_image
 from repro.core.router import parse_node_index
 from repro.core.tuner import MarginalHitTuner, TunerConfig
+from repro.kernels.conv3x3 import PATHS as conv_paths
 from repro.store.api import StoreConfig
 from repro.store.tiers import DurableTier, RecipeTier
 from repro.store.walk import TierWalk
@@ -266,11 +268,16 @@ class DecodeBatcher:
         key = (int(bucket), tuple(int(v) for v in latent_hwc))
         if key in self._warm:
             return 0.0
-        with TraceAnnotation("lb.warm_up", bucket=key[0]):
+        with TraceAnnotation("lb.warm_up", bucket=key[0]) as span:
             t0 = time.perf_counter()
+            before = dict(conv_paths)
             z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
             np.asarray(self._decode_fn(z))
             self._warm.add(key)
+            # banded convs this compile traced on each path (0 and 0 when
+            # the decode's trace was already cached)
+            span.set_metadata(**{k: conv_paths[k] - before[k]
+                                 for k in conv_paths})
             return time.perf_counter() - t0
 
     def prewarm(self, latent_hwc: Tuple[int, int, int]) -> Dict[int, float]:

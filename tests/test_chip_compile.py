@@ -11,6 +11,8 @@ The persistent compilation cache is off around the compiles: a compile for
 a described chip could be written but never read back here.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -65,6 +67,39 @@ def test_gn_silu_conv3x3_1024_128(one_chip):
                 lambda x, s, g, w, b: gn_silu_conv3x3(x, s, g, w, b),
                 (1, 1024, 1024, 128), (128,), (128,), (3, 3, 128, 128),
                 (128,))
+
+
+def test_gn_silu_conv3x3_128_512(one_chip):
+    """The mid block's and lowest up level's res-block conv at
+    128x128x512."""
+    compile_for(one_chip,
+                lambda x, s, g, w, b: gn_silu_conv3x3(x, s, g, w, b),
+                (1, 128, 128, 512), (512,), (512,), (3, 3, 512, 512),
+                (512,))
+
+
+_SHAPE = re.compile(r"f32\[([0-9,]+)\]")
+
+
+@pytest.mark.parametrize("hw,c,rows", [(1024, 128, 8), (128, 512, 16)])
+def test_gn_silu_conv3x3_call_shapes(one_chip, hw, c, rows):
+    """The compiled conv call keeps the shapes the benchmark's trace
+    reader matches: operand 0 the row-banded input ``[N * bands, rows+2,
+    W+2, Cin]``, the result's last axis ``Cout``; the F(2,3) filter
+    ``[4, 3, Cin, Cout]`` follows the band."""
+    compiled = compile_for(
+        one_chip, lambda x, s, g, w, b: gn_silu_conv3x3(x, s, g, w, b),
+        (1, hw, hw, c), (c,), (c,), (3, 3, c, c), (c,))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if re.match(r"\s*%gn_silu_conv3x3(\.\d+)? = f32\[", ln)]
+    assert len(calls) == 1                 # the conv (stats return a tuple)
+    shapes = [tuple(int(v) for v in m.split(","))
+              for m in _SHAPE.findall(calls[0])]
+    result = shapes[0]
+    operands = [s for s in shapes[1:] if len(s) == 4]
+    assert result[-1] == c
+    assert operands[0] == (hw // rows, rows + 2, hw + 2, c)
+    assert operands[1] == (4, 3, c, c)
 
 
 def test_upsample_conv3x3_512_from_128(one_chip):

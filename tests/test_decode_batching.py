@@ -178,3 +178,48 @@ class TestEngineStillServes:
         assert s["total"] == 300
         assert s["image_hit"] > 0
         assert sum(o != "full_miss" for o in outcomes[-100:]) > 50
+
+
+class _Spans:
+    """Stands in for ``TraceAnnotation``: records each span's name and
+    its stats, those given at its start and those set at its end."""
+
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name, **stats):
+        self.opened.append((name, stats))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        self.opened[-1][1].update(stats)
+
+
+@pytest.mark.parametrize("latent_hw,winograd,direct", [
+    (4, 14, 0),      # every band even: F(2,3) along rows
+    (3, 5, 9),       # the 3-row level's bands are odd: the nine-tap loop
+])
+def test_warm_up_span_counts_conv_paths(monkeypatch, latent_hw, winograd,
+                                        direct):
+    """A warm-up's ``lb.warm_up`` span carries the banded convs its
+    compile traced on each path: the demo decoder has 14 (conv_in, two
+    in each of six res blocks, the output epilogue); a warm bucket opens
+    no second span."""
+    from repro.serve import engine
+    from repro.vae.model import DEMO_VAE
+    spans = _Spans()
+    monkeypatch.setattr(engine, "TraceAnnotation", spans)
+    vae = VAE(DEMO_VAE, seed=0, with_encoder=False, impl="pallas_interpret")
+    batcher = DecodeBatcher(vae, buckets=(1,))
+    shape = (latent_hw, latent_hw, DEMO_VAE.latent_channels)
+    batcher.prewarm(shape)
+    batcher.prewarm(shape)
+    warm = [s for name, s in spans.opened if name == "lb.warm_up"]
+    assert warm == [{"bucket": 1, "winograd_rows": winograd,
+                     "direct": direct}]

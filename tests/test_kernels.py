@@ -47,6 +47,7 @@ def test_gn_silu(shape, groups, dtype):
 @pytest.mark.parametrize("n,h,w,cin,cout,groups", [
     (1, 8, 8, 16, 32, 4), (2, 16, 12, 8, 8, 2), (1, 32, 32, 64, 128, 8),
     (1, 5, 7, 4, 4, 2), (3, 4, 4, 32, 16, 8), (1, 16, 16, 64, 64, 32),
+    (1, 8, 13, 16, 16, 4), (2, 2, 10, 8, 8, 2),
 ])
 def test_gn_silu_conv3x3(n, h, w, cin, cout, groups):
     """Fused GN+SiLU+conv3x3 (res-block hot path) vs composed oracles."""
@@ -162,7 +163,8 @@ def test_upsample_conv3x3_matches_unfused_decode_path():
 
 @pytest.mark.parametrize("n,h,w,cin,groups", [
     (1, 8, 8, 16, 4), (2, 16, 12, 8, 2), (1, 5, 7, 4, 2),
-    (1, 32, 32, 64, 8), (3, 4, 4, 32, 8),
+    (1, 32, 32, 64, 8), (3, 4, 4, 32, 8), (1, 8, 13, 16, 4),
+    (2, 2, 10, 8, 2),
 ])
 def test_output_epilogue(n, h, w, cin, groups):
     """Fused GN+SiLU+conv_out+clamp+uint8 vs the composed oracle: any
@@ -236,7 +238,8 @@ def test_decode_attention(n, hq, hkv, S, d):
 
 @pytest.mark.parametrize("n,h,w,cin,cout", [
     (1, 8, 8, 16, 32), (2, 16, 12, 8, 8), (1, 32, 32, 64, 128),
-    (1, 5, 7, 4, 4), (1, 9, 16, 32, 16),
+    (1, 5, 7, 4, 4), (1, 9, 16, 32, 16), (1, 8, 13, 16, 16),
+    (2, 2, 10, 8, 8),
 ])
 def test_conv3x3(n, h, w, cin, cout):
     x = arr((n, h, w, cin))
@@ -282,3 +285,85 @@ def test_chunked_model_forms_match_ref():
     orf, srf = ref.rwkv6_scan_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(oc), np.asarray(orf), atol=3e-4)
     np.testing.assert_allclose(np.asarray(sc), np.asarray(srf), atol=3e-4)
+
+
+def _banded(kernel, x, wt, b, rows, w_scale=None):
+    """One banded conv op and its oracle (``w_scale`` dequantizes int8
+    taps for the oracle)."""
+    cin = x.shape[-1]
+    wd = wt if w_scale is None else wt.astype(jnp.float32) * w_scale
+    if kernel == "conv3x3":
+        return (conv3x3(x, wt, b, rows=rows, interpret=True,
+                        w_scale=w_scale), ref.conv3x3_ref(x, wd, b))
+    s, gb = arr((cin,)), arr((cin,))
+    fn, oracle = ((gn_silu_conv3x3, ref.gn_silu_conv3x3_ref)
+                  if kernel == "gn_silu_conv3x3"
+                  else (output_epilogue, ref.output_epilogue_ref))
+    return (fn(x, s, gb, wt, b, groups=4, rows=rows, interpret=True,
+               w_scale=w_scale), oracle(x, s, gb, wd, b, groups=4))
+
+
+def _assert_close(kernel, out, want):
+    if kernel == "output_epilogue":
+        lsb = np.abs(np.asarray(out, np.int16) - np.asarray(want, np.int16))
+        assert lsb.max() <= 1
+    else:
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want, np.float32), atol=1e-4)
+
+
+@pytest.mark.parametrize("kernel", ["conv3x3", "gn_silu_conv3x3",
+                                    "output_epilogue"])
+@pytest.mark.parametrize("h,rows,path", [
+    (8, 8, "winograd_rows"), (12, 4, "winograd_rows"), (6, 2, "winograd_rows"),
+    (9, 3, "direct"), (8, 1, "direct"),
+])
+def test_banded_conv_path(kernel, h, rows, path):
+    """The band's height picks the path (F(2,3) along rows for an even
+    band, the nine-tap loop for an odd one), and both match the oracle."""
+    from repro.kernels.conv3x3 import plan
+    cout = 3 if kernel == "output_epilogue" else 16
+    x = arr((2, h, 10, 16))
+    wt = arr((3, 3, 16, cout), scale=0.1)
+    b = arr((cout,), scale=0.1)
+    assert plan(x.shape, cout, 4, rows, 128,
+                kernel != "conv3x3") == (rows, cout, path)
+    _assert_close(kernel, *_banded(kernel, x, wt, b, rows))
+
+
+@pytest.mark.parametrize("kernel", ["conv3x3", "gn_silu_conv3x3",
+                                    "output_epilogue"])
+@pytest.mark.parametrize("storage", ["int8", "bfloat16"])
+def test_banded_conv_weight_storage(kernel, storage):
+    """int8 taps with a per-channel ``w_scale`` and bf16 taps take the
+    fp32 row transform and match the oracle on the dequantized filter."""
+    cout = 3 if kernel == "output_epilogue" else 16
+    x = arr((1, 8, 12, 16))
+    b = arr((cout,), scale=0.1)
+    w_scale = None
+    if storage == "int8":
+        wt = jnp.asarray(R.integers(-127, 128, (3, 3, 16, cout)), jnp.int8)
+        w_scale = jnp.asarray(R.uniform(0.2, 1.0, (cout,)) / 1270,
+                              jnp.float32)
+    else:
+        wt = arr((3, 3, 16, cout), jnp.bfloat16, scale=0.1)
+    _assert_close(kernel, *_banded(kernel, x, wt, b, 8, w_scale))
+
+
+def test_winograd_weights_reproduce_direct_rows():
+    """F(2,3) along rows: the transformed rows t_k times the transformed
+    filter U[k] give the direct three-row sums of both output rows."""
+    from repro.kernels.conv3x3 import winograd_weights
+    d = R.standard_normal((4, 5, 8))                  # band rows, W, Cin
+    w = R.standard_normal((3, 3, 8, 6)).astype(np.float32)
+    u = np.asarray(winograd_weights(jnp.asarray(w)), np.float64)
+    assert u.shape == (4, 3, 8, 6)
+    t = (d[0] - d[2], d[1] + d[2], d[2] - d[1], d[1] - d[3])
+    for dx in range(3):
+        m = [t[k] @ u[k, dx] for k in range(4)]
+        y0 = sum(d[dy] @ w[dy, dx] for dy in range(3))
+        y1 = sum(d[dy + 1] @ w[dy, dx] for dy in range(3))
+        np.testing.assert_allclose(m[0] + m[1] + m[2], y0, rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(m[1] - m[2] - m[3], y1, rtol=1e-5,
+                                   atol=1e-5)
