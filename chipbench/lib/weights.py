@@ -1,20 +1,20 @@
 """The decoder's weights and the stored latents, made from ``--seed``.
 
 Weights are made on the device in one jitted call, at fp32 (the precision
-the configurations state), in the layout of the AutoencoderKL decoder
-(``chipbench/references/autoencoderkl.py``): the benchmark hands the same
-tree to the system under test and to the reference.  Convolutions and
-dense layers draw N(0, 1 / fan_in), biases and GroupNorm affines are small
-and non-zero so that every parameter shows in the output, and
-``conv_out`` is scaled by ``out_gain`` so that served images sit inside
-the display range instead of saturating it (a trained decoder's outputs
-do).  Latents are seeded standard normals in fp16, one stream per object.
+the configurations state): the decoder family's reference module lays out
+the tree (``weight_tree(arch, init, normal)``, calling ``normal(shape,
+std, mean=0.0)`` once per leaf in tree order), and this module fills it
+from one normal draw of the tree's whole size, sliced leaf by leaf in that
+order.  The benchmark hands the same tree to the system under test and to
+the reference.  ``init`` is the configuration's ``weights`` group, whose
+stds the family reads.  Latents are seeded standard normals in fp16, one
+stream per object.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
@@ -26,7 +26,8 @@ def subseed(seed: int, tag: str) -> int:
     return int(np.random.SeedSequence(words).generate_state(1)[0] >> 1)
 
 
-def _tree(key, arch: Dict[str, Any], w: Dict[str, float]):
+def _tree(key, weight_tree: Callable, arch: Dict[str, Any],
+          init: Dict[str, float]):
     """One normal draw for the whole tree, sliced into its leaves."""
     import jax
     import jax.numpy as jnp
@@ -37,48 +38,7 @@ def _tree(key, arch: Dict[str, Any], w: Dict[str, float]):
         leaves.append((shape, std, mean))
         return len(leaves) - 1
 
-    def conv(k, cin, cout, gain=1.0):
-        return {"w": normal((k, k, cin, cout), gain / np.sqrt(k * k * cin)),
-                "b": normal((cout,), w["bias_std"])}
-
-    def gn(c):
-        return {"scale": normal((c,), w["norm_affine_std"], 1.0),
-                "bias": normal((c,), w["norm_affine_std"])}
-
-    def dense(cin, cout):
-        return {"w": normal((cin, cout), 1.0 / np.sqrt(cin)),
-                "b": normal((cout,), w["bias_std"])}
-
-    def resblock(cin, cout):
-        p = {"norm1": gn(cin), "conv1": conv(3, cin, cout),
-             "norm2": gn(cout), "conv2": conv(3, cout, cout)}
-        if cin != cout:
-            p["shortcut"] = conv(1, cin, cout)
-        return p
-
-    chs = list(arch["block_out_channels"])
-    top = chs[-1]
-    tree = {"conv_in": conv(3, arch["latent_channels"], top),
-            "mid": {"res1": resblock(top, top),
-                    "attn": {"norm": gn(top), "q": dense(top, top),
-                             "k": dense(top, top), "v": dense(top, top),
-                             "proj": dense(top, top)},
-                    "res2": resblock(top, top)},
-            "up": []}
-    cin = top
-    for i, cout in enumerate(reversed(chs)):
-        blocks = []
-        for _ in range(arch["layers_per_block"] + 1):
-            blocks.append(resblock(cin, cout))
-            cin = cout
-        level = {"blocks": blocks}
-        if i < len(chs) - 1:
-            level["upsample"] = {"conv": conv(3, cout, cout)}
-        tree["up"].append(level)
-    tree["norm_out"] = gn(chs[0])
-    tree["conv_out"] = conv(3, chs[0], arch["out_channels"],
-                            gain=w["out_gain"])
-
+    tree = weight_tree(arch, init, normal)
     sizes = [int(np.prod(shape)) for shape, _, _ in leaves]
     flat = jax.random.normal(key, (sum(sizes),), jnp.float32)
     offsets = np.cumsum([0] + sizes)
@@ -88,25 +48,31 @@ def _tree(key, arch: Dict[str, Any], w: Dict[str, float]):
 
 
 @functools.lru_cache(maxsize=None)
-def _maker(arch_items: Tuple, w_items: Tuple):
+def _maker(weight_tree: Callable, arch_items: Tuple, w_items: Tuple):
     import jax
-    return jax.jit(lambda key: _tree(key, dict(arch_items), dict(w_items)))
+    return jax.jit(lambda key: _tree(key, weight_tree, dict(arch_items),
+                                     dict(w_items)))
+
+
+def _frozen(v):
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
 
 
 def freeze(d: Dict[str, Any]) -> Tuple:
-    return tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
-                        for k, v in d.items()))
+    """``d``'s items, sorted, with lists (nested ones too) as tuples: a
+    key for the caches of jitted makers."""
+    return tuple(sorted((k, _frozen(v)) for k, v in d.items()))
 
 
-def decoder_weights(arch: Dict[str, Any], init: Dict[str, float],
+def decoder_weights(family, arch: Dict[str, Any], init: Dict[str, float],
                     seed: int, device=None):
-    """The fp32 decoder tree for ``seed``, made on ``device`` in one
-    jitted call."""
+    """The fp32 decoder tree of ``family`` (the configuration's reference
+    module) for ``seed``, made on ``device`` in one jitted call."""
     import jax
     key = jax.random.PRNGKey(subseed(seed, "weights"))
     if device is not None:
         key = jax.device_put(key, device)
-    return _maker(freeze(arch), freeze(init))(key)
+    return _maker(family.weight_tree, freeze(arch), freeze(init))(key)
 
 
 def latent(seed: int, oid: int, shape: Tuple[int, int, int]) -> np.ndarray:

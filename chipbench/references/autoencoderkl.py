@@ -19,18 +19,48 @@ weights.  Parameters are the benchmark's own (``chipbench/lib/weights``),
 never read from the system under test.  ``precision="float32"`` runs
 every product at full fp32 (``Precision.HIGHEST``); ``"bfloat16"`` holds
 weights and activations in bf16 with default products — the control.
+
+A decoder family enters the benchmark as one such module under
+``chipbench/references/``, named by a configuration's ``reference``; the
+harness's shared files (``run.py``, ``lib/``) name no family.  Besides
+``decode``, ``to_u8`` and ``compiled``, a family module provides:
+
+* ``PROGRAM_KEYS``: the program decoder's attribute -> the architecture
+  key it must equal (``run.py`` refuses a run where one differs);
+* ``latent_hwc(arch)``: the stored latent's (h, w, C) at the
+  configuration's ``resolution``;
+* ``weight_tree(arch, init, normal)``: the parameter tree, each leaf the
+  value of ``normal(shape, std, mean=0.0)``, called in tree order
+  (``lib/weights.py`` fills it from one draw);
+* ``calls(arch)``: every matmul-bearing call of one decode for one image,
+  in program order (``lib/flops.py:Call``), read by the per-layer
+  metrics.
+
+The architecture also states ``resolution`` and ``out_channels``, which
+``run.py`` reads for the served image's size.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from lib.flops import F32, Call, conv_call
 
 EPS = 1e-6
+
+#: The program decoder's sizes that must agree with the configuration.
+PROGRAM_KEYS = {"latent_channels": "latent_channels",
+                "block_out_channels": "block_out_channels",
+                "layers_per_block": "layers_per_block",
+                "groups": "norm_num_groups",
+                "scaling_factor": "scaling_factor",
+                "shift_factor": "shift_factor"}
 
 
 def _settings(precision: str):
@@ -133,3 +163,114 @@ def compiled(arch_items: tuple, precision: str):
     (params, latents [N, h, w, C]) -> uint8 images."""
     arch = dict(arch_items)
     return jax.jit(lambda p, z: to_u8(decode(p, z, arch, precision)))
+
+
+def latent_hwc(arch: Dict[str, Any]) -> Tuple[int, int, int]:
+    """The latent of one image: a 2x upsampler between every two levels."""
+    s = arch["resolution"] // 2 ** (len(arch["block_out_channels"]) - 1)
+    return (s, s, arch["latent_channels"])
+
+
+def weight_tree(arch: Dict[str, Any], init: Dict[str, float], normal):
+    """The decoder's parameter tree, leaf by leaf from ``normal``.
+
+    Convolutions and dense layers draw N(0, 1 / fan_in), biases and
+    GroupNorm affines are small and non-zero so that every parameter shows
+    in the output, and ``conv_out`` is scaled by ``init["out_gain"]`` so
+    that served images sit inside the display range instead of saturating
+    it (a trained decoder's outputs do)."""
+
+    def conv(k, cin, cout, gain=1.0):
+        return {"w": normal((k, k, cin, cout), gain / np.sqrt(k * k * cin)),
+                "b": normal((cout,), init["bias_std"])}
+
+    def gn(c):
+        return {"scale": normal((c,), init["norm_affine_std"], 1.0),
+                "bias": normal((c,), init["norm_affine_std"])}
+
+    def dense(cin, cout):
+        return {"w": normal((cin, cout), 1.0 / np.sqrt(cin)),
+                "b": normal((cout,), init["bias_std"])}
+
+    def resblock(cin, cout):
+        p = {"norm1": gn(cin), "conv1": conv(3, cin, cout),
+             "norm2": gn(cout), "conv2": conv(3, cout, cout)}
+        if cin != cout:
+            p["shortcut"] = conv(1, cin, cout)
+        return p
+
+    chs = list(arch["block_out_channels"])
+    top = chs[-1]
+    tree = {"conv_in": conv(3, arch["latent_channels"], top),
+            "mid": {"res1": resblock(top, top),
+                    "attn": {"norm": gn(top), "q": dense(top, top),
+                             "k": dense(top, top), "v": dense(top, top),
+                             "proj": dense(top, top)},
+                    "res2": resblock(top, top)},
+            "up": []}
+    cin = top
+    for i, cout in enumerate(reversed(chs)):
+        blocks = []
+        for _ in range(arch["layers_per_block"] + 1):
+            blocks.append(resblock(cin, cout))
+            cin = cout
+        level = {"blocks": blocks}
+        if i < len(chs) - 1:
+            level["upsample"] = {"conv": conv(3, cout, cout)}
+        tree["up"].append(level)
+    tree["norm_out"] = gn(chs[0])
+    tree["conv_out"] = conv(3, chs[0], arch["out_channels"],
+                            gain=init["out_gain"])
+    return tree
+
+
+def calls(arch: Dict[str, Any]) -> List[Call]:
+    """Every matmul-bearing call of one decode, in program order, for one
+    image of ``arch["resolution"]`` pixels a side.
+
+    The decode is a chain of 3x3 convolutions (``conv3x3``: ``conv_in``,
+    both convs of every res block with GroupNorm + SiLU fused in front,
+    and the uint8 output epilogue), phase-decomposed 2x upsampler
+    convolutions (``upsample_conv3x3``: each of the four output phases is
+    a 2x2 collapsed filter over the pre-upsample grid, 16 taps in all
+    instead of the 36 of a 3x3 conv over the upsampled tensor), 1x1
+    shortcut convs and a single-head attention over the latent grid in
+    the mid block."""
+    chs = list(reversed(arch["block_out_channels"]))
+    groups_layers = arch["layers_per_block"] + 1
+    h = latent_hwc(arch)[0]
+    top = chs[0]
+    out = [conv_call("conv_in", h, h, arch["latent_channels"], top)]
+
+    def resblock(tag, cin, cout, hh):
+        res = [conv_call(f"{tag}.conv1", hh, hh, cin, cout),
+               conv_call(f"{tag}.conv2", hh, hh, cout, cout)]
+        if cin != cout:
+            res.append(conv_call(f"{tag}.shortcut", hh, hh, cin, cout, k=1,
+                                 kernel="xla"))
+        return res
+
+    out += resblock("mid.res1", top, top, h)
+    n = h * h
+    out.append(Call("xla", "mid.attn.proj", h, top, top,
+                    4 * 2.0 * n * top * top,
+                    5 * n * top * F32, 4 * (top * top + top) * F32))
+    out.append(Call("flash_attention", "mid.attn.core", h, top, top,
+                    4.0 * n * n * top,
+                    4 * n * top * F32, 0.0))
+    out += resblock("mid.res2", top, top, h)
+    cin = top
+    for i, cout in enumerate(chs):
+        for j in range(groups_layers):
+            out += resblock(f"up{i}.res{j}", cin, cout, h)
+            cin = cout
+        if i < len(chs) - 1:
+            out.append(Call(
+                "upsample_conv3x3", f"up{i}.upsample", h, cout, cout,
+                2.0 * (2 * h) * (2 * h) * cout * cout * 4,
+                h * h * cout * F32 + 4 * h * h * cout * F32,
+                (9 * cout * cout + cout) * F32))
+            h *= 2
+    out.append(conv_call("conv_out", h, h, chs[-1], arch["out_channels"],
+                         out_itemsize=1))
+    return out

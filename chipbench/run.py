@@ -16,6 +16,11 @@ of standard output, one JSON object: ``correct``, ``attempted``,
 ``--trace 1`` its per-layer ones, read from a profiler trace of the
 window), ``device``, ``breakdown`` (with ``--trace 1``) and ``checks``.
 
+What belongs to a decoder family (the check of the program's sizes, the
+weight tree, the latent's shape and the decode's calls) comes from the
+module that the configuration's ``reference`` names
+(``chipbench/references/<reference>.py``), beside the plain reference.
+
 Without a TPU, or with fewer chips than the cell asks for, it exits with
 code 2 and prints no result.
 """
@@ -50,13 +55,6 @@ from lib.weights import decoder_weights, freeze, latent  # noqa: E402
 
 #: How long requests due in an open-loop window may drain after it.
 DRAIN_S = 30.0
-#: The program's decoder sizes that must agree with the configuration.
-ARCH_KEYS = {"latent_channels": "latent_channels",
-             "block_out_channels": "block_out_channels",
-             "layers_per_block": "layers_per_block",
-             "groups": "norm_num_groups",
-             "scaling_factor": "scaling_factor",
-             "shift_factor": "shift_factor"}
 
 
 def log(msg: str) -> None:
@@ -84,15 +82,22 @@ def enable_compile_cache(root: str) -> str:
     return path
 
 
-def program_vae(cell: Cell, weights, precision: str):
+def _plain(v):
+    """``v`` with tuples, nested ones too, as lists: how JSON states it."""
+    return [_plain(x) for x in v] if isinstance(v, (tuple, list)) else v
+
+
+def program_vae(cell: Cell, family, weights, precision: str):
     """The system's VAE with the benchmark's weights loaded, after
-    checking that its named decoder has the configuration's sizes."""
+    checking that its named decoder has the configuration's sizes: each
+    attribute that the family module's ``PROGRAM_KEYS`` names equals the
+    architecture key it maps to."""
     from repro.vae.model import CONFIGS, VAE
     vcfg = CONFIGS[cell.config["decoder"]]
     arch = cell.config["architecture"]
-    for ours, theirs in ARCH_KEYS.items():
+    for ours, theirs in family.PROGRAM_KEYS.items():
         a, b = getattr(vcfg, ours), arch[theirs]
-        if (list(a) if isinstance(a, tuple) else a) != b:
+        if _plain(a) != b:
             raise ValueError(f"decoder {vcfg.name}: {ours}={a!r} but the "
                              f"configuration states {theirs}={b!r}")
     vae = VAE(vcfg, seed=0, with_encoder=False)
@@ -119,12 +124,6 @@ def build_box(cell: Cell, vae, latent_bytes: int):
                           step=float(s["tuner_step"])),
         decode_buckets=tuple(int(b) for b in s["decode_buckets"]))
     return LatentBox.engine(vae=vae, config=cfg)
-
-
-def latent_hwc(cell: Cell):
-    arch = cell.config["architecture"]
-    s = arch["resolution"] // 2 ** (len(arch["block_out_channels"]) - 1)
-    return (s, s, arch["latent_channels"])
 
 
 def decode_scratch_bytes(vae, buckets, hwc) -> Dict[int, int]:
@@ -171,7 +170,8 @@ def run(args, cell: Cell, require_tpu: bool = True,
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
     counter = serving.CompileCounter()
     arch = cell.config["architecture"]
-    hwc = latent_hwc(cell)
+    family = cell.reference()
+    hwc = family.latent_hwc(arch)
     precision = precision or cell.config["precision"]
     log(f"{cell.name}: platform={dev.platform} kind={dev.device_kind} "
         f"devices={len(devs)} seed={args.seed} compile_cache={cache}")
@@ -179,8 +179,9 @@ def run(args, cell: Cell, require_tpu: bool = True,
     # -- set-up ---------------------------------------------------------------
     from repro.compression.latentcodec import compress_latent
     traffic = make_traffic(cell.mix, args.seed, args.seconds)
-    weights = decoder_weights(arch, cell.config["weights"], args.seed, dev)
-    vae = program_vae(cell, weights, precision)
+    weights = decoder_weights(family, arch, cell.config["weights"],
+                              args.seed, dev)
+    vae = program_vae(cell, family, weights, precision)
     blob = compress_latent(latent(args.seed, int(traffic.objects[0]), hwc))
     box = build_box(cell, vae, len(blob))
     if patch is not None:
@@ -249,8 +250,7 @@ def run(args, cell: Cell, require_tpu: bool = True,
     if trace_dir:
         trace_summary = profile.reduce_dir(trace_dir)
         profile.remove(trace_dir)
-    ref_mod = cell.reference()
-    ref_fn = ref_mod.compiled(freeze(arch), cell.config["precision"])
+    ref_fn = family.compiled(freeze(arch), cell.config["precision"])
 
     def reference(z):
         zb = jax.device_put(z[None], dev)
@@ -280,10 +280,10 @@ def run(args, cell: Cell, require_tpu: bool = True,
               "attempted": int(len(window.ids)),
               "failed": int((~window.served).sum())}
     if args.trace:
+        calls = family.calls(arch)
         ctx = types.SimpleNamespace(
             cell=cell, window=window, trace=trace_summary, peaks=peaks,
-            calls=flops.decoder_calls(arch),
-            flops_per_image=flops.decode_flops_per_image(arch))
+            calls=calls, flops_per_image=flops.flops_per_image(calls))
         metrics = {}
         for m in cell.per_layer:
             v = cell.reader(m["name"]).read(ctx)

@@ -1,6 +1,6 @@
 """Where the benchmark and the repository are, with both on ``sys.path``
 (the harness's ``lib`` and ``run``, and the system under test's ``src``),
-and a scratch checkout with tiny CPU cells.  (Not a ``conftest.py``: the
+and scratch checkouts with tiny CPU cells.  (Not a ``conftest.py``: the
 repository's own tests import theirs by that name.)"""
 
 import json
@@ -55,3 +55,81 @@ def tiny_root(tmp_path_factory):
         b["end_to_end"].append(SERVED_RPS | {"workloads": ["demo.scan"]})
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     return str(root)
+
+
+#: Appended to a copy of ``references/autoencoderkl.py``: a decoder family
+#: that exists only in a test's checkout.  Each of its family names notes
+#: in ``<module>.seen`` that the harness read it, and ``PROGRAM_KEYS``
+#: checks the program's latent channels against a key that only this
+#: family's configuration states.
+FAMILY_SENTINELS = """
+
+import os as _os
+
+
+def _seen(name):
+    with open(_os.path.splitext(__file__)[0] + ".seen", "a") as f:
+        f.write(name + "\\n")
+
+
+PROGRAM_KEYS = dict(PROGRAM_KEYS, latent_channels="sentinel_latent_channels")
+_kl = (latent_hwc, weight_tree, calls)
+
+
+def latent_hwc(arch):
+    _seen("latent_hwc")
+    return _kl[0](arch)
+
+
+def weight_tree(arch, init, normal):
+    _seen("weight_tree")
+    return _kl[1](arch, init, normal)
+
+
+def calls(arch):
+    _seen("calls")
+    return _kl[2](arch)
+"""
+
+
+def family_root(root, sentinel_latent_channels: int) -> str:
+    """A checkout with the benchmark as it is plus, as new files only, the
+    decoder family ``kl_copy`` (:data:`FAMILY_SENTINELS`), a configuration
+    ``copy_vae-32`` that names it (the demo decoder's, with
+    ``sentinel_latent_channels`` stated), a tiny scan mix and a cell
+    ``copy.scan`` that uses them."""
+    root = str(root)
+    bench = os.path.join(root, "chipbench")
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    refs = os.path.join(bench, "references")
+    with open(os.path.join(refs, "autoencoderkl.py")) as f:
+        source = f.read()
+    with open(os.path.join(refs, "kl_copy.py"), "w") as f:
+        f.write(source + FAMILY_SENTINELS)
+    with open(os.path.join(DATA, "demo_vae-32.json")) as f:
+        cfg = json.load(f)
+    cfg["name"], cfg["reference"] = "copy_vae-32", "kl_copy"
+    cfg["architecture"]["sentinel_latent_channels"] = sentinel_latent_channels
+    os.makedirs(os.path.join(bench, "configs"), exist_ok=True)
+    with open(os.path.join(bench, "configs", "copy_vae-32.json"), "w") as f:
+        json.dump(cfg, f)
+    shutil.copy(os.path.join(DATA, "tiny_scan.json"),
+                os.path.join(bench, "mixes"))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        b = json.load(f)
+    b["configs"].append({"name": "copy_vae-32", "source": "test",
+                         "file": "chipbench/configs/copy_vae-32.json",
+                         "reduced": [], "why": "CPU tests"})
+    b["workloads"].append({"name": "copy.scan", "config": "copy_vae-32",
+                           "traffic": "tiny_scan", "chips": 1,
+                           "why": "CPU tests"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        w = m.get("workloads")
+        if w and "sd35-1024.scan" in w:
+            w.append("copy.scan")
+    if not any("copy.scan" in m.get("workloads", ()) for m in b["end_to_end"]):
+        b["end_to_end"].append(SERVED_RPS | {"workloads": ["copy.scan"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(b, f)
+    return root

@@ -1,5 +1,6 @@
-"""The decoder's operation counts against closed forms worked out by hand
-from the published architecture (not against the program's own count)."""
+"""The AutoencoderKL family's operation counts against closed forms
+worked out by hand from the published architecture (not against the
+program's own count)."""
 
 import json
 import os
@@ -9,6 +10,7 @@ import pytest
 import chipbench_paths  # noqa: F401  (puts the harness on sys.path)
 
 from lib import flops
+from references import autoencoderkl as KL
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "configs")
@@ -41,7 +43,7 @@ def closed_form(lat, res, c=(128, 256, 512, 512), layers=3):
 def test_flops_per_image(name, gflop):
     with open(os.path.join(CONFIGS, f"{name}.json")) as f:
         arch = json.load(f)["architecture"]
-    got = flops.decode_flops_per_image(arch)
+    got = flops.flops_per_image(KL.calls(arch))
     assert got == pytest.approx(closed_form(arch["latent_channels"],
                                             arch["resolution"]), rel=1e-12)
     assert got / 1e9 == pytest.approx(gflop, abs=1e-3)
@@ -53,14 +55,14 @@ def test_attention_counted_once():
     with open(os.path.join(CONFIGS, "sd35_vae-1024.json")) as f:
         arch = json.load(f)["architecture"]
     n = 128 * 128
-    assert (flops.decode_flops_per_image(arch) + 4 * n * n * 512) / 1e9 \
+    assert (flops.flops_per_image(KL.calls(arch)) + 4 * n * n * 512) / 1e9 \
         == pytest.approx(9475.7716, abs=1e-3)
 
 
 def test_least_time_is_the_larger_bound():
-    call = flops.decoder_calls({"block_out_channels": [128, 256, 512, 512],
-                                "layers_per_block": 2, "latent_channels": 16,
-                                "resolution": 1024, "out_channels": 3})[-1]
+    call = KL.calls({"block_out_channels": [128, 256, 512, 512],
+                     "layers_per_block": 2, "latent_channels": 16,
+                     "resolution": 1024, "out_channels": 3})[-1]
     assert call.what == "conv_out"
     t = flops.least_seconds(call, 2, 197e12, 819e9)
     assert t == max(call.flops * 2 / 197e12, call.bytes_for(2) / 819e9)

@@ -1,6 +1,6 @@
 """The harness finds every cell, configuration, mix, metric and reference
-in ``BENCHMARK.json`` by name, and a new one is added as new files plus a
-new entry, with no edit to an existing file."""
+in ``BENCHMARK.json`` by name, and a new one, a decoder family too, is
+added as new files plus a new entry, with no edit to an existing file."""
 
 import json
 import os
@@ -8,13 +8,18 @@ import shutil
 
 import pytest
 
-from chipbench_paths import BENCH, REPO, SERVED_RPS
+from chipbench_paths import BENCH, REPO, SERVED_RPS, family_root
 
+import run as R
 from lib.cells import load_cell
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+#: The harness's shared files, which no new cell, mix, metric,
+#: configuration or decoder family edits.
+SHARED = ("run.py", "lib/cells.py", "lib/traffic.py", "lib/weights.py",
+          "lib/flops.py", "lib/readers.py", "lib/check.py")
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -25,9 +30,12 @@ def test_cell_resolves(name):
     assert len(cell.end_to_end) >= 2 and cell.per_layer
     for m in cell.per_layer:
         assert callable(cell.reader(m["name"]).read)
-    assert callable(cell.reference().decode)
     ref = cell.reference()
     assert ref.__file__.startswith(BENCH)
+    assert callable(ref.decode)
+    assert isinstance(ref.PROGRAM_KEYS, dict) and ref.PROGRAM_KEYS
+    for name in ("latent_hwc", "weight_tree", "calls"):
+        assert callable(getattr(ref, name))
 
 
 def test_every_metric_has_a_reader_and_cell():
@@ -49,7 +57,8 @@ def test_new_files_extend_the_benchmark(tmp_path):
     shutil.copytree(BENCH, tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: open(os.path.join(BENCH, p), "rb").read()
-              for p in ("lib/cells.py", "run.py", "lib/traffic.py")}
+              for p in ("lib/cells.py", "run.py", "lib/traffic.py",
+                        "lib/weights.py", "lib/flops.py", "lib/readers.py")}
     mix = json.load(open(os.path.join(BENCH, "mixes",
                                       "scan_closed.json")))
     mix["corpus"] = 512
@@ -80,6 +89,43 @@ def test_new_files_extend_the_benchmark(tmp_path):
     assert cell.reader("calls_per_s").read is not None
     for p, data in before.items():
         assert open(tmp_path / "chipbench" / p, "rb").read() == data
+
+
+def _run_family(root, trace):
+    args = R.parse(["--workload", "copy.scan", "--seed", str(2 ** 35 + 19),
+                    "--seconds", "0.5", "--trace", str(trace)])
+    return R.run(args, load_cell("copy.scan", root), require_tpu=False,
+                 compile_cache=False)
+
+
+def test_new_family_is_new_files(tmp_path):
+    """A decoder family that exists only in the checkout, as a module, a
+    configuration, a mix and a cell: a run takes the program check, the
+    weight tree, the latent shape and the calls from that module, with
+    every shared file of the harness as it is."""
+    root = family_root(tmp_path, sentinel_latent_channels=4)
+    module = tmp_path / "chipbench" / "references" / "kl_copy.py"
+    seen = module.with_suffix(".seen")
+    assert load_cell("copy.scan", root).reference().__file__ == str(module)
+    res = _run_family(root, trace=0)
+    assert res["correct"] is True, res["checks"]
+    assert sorted(seen.read_text().split()) == ["latent_hwc", "weight_tree"]
+    seen.unlink()
+    res = _run_family(root, trace=1)
+    assert res["correct"] is True, res["checks"]
+    assert "calls" in seen.read_text().split()
+    for p in SHARED:
+        assert (tmp_path / "chipbench" / p).read_bytes() == \
+            open(os.path.join(BENCH, p), "rb").read()
+
+
+def test_new_family_checks_the_program(tmp_path):
+    """The family's ``PROGRAM_KEYS`` decides what the program's decoder
+    must match: a key that only the new family maps, stated otherwise in
+    its configuration, refuses the run and names the key."""
+    root = family_root(tmp_path, sentinel_latent_channels=5)
+    with pytest.raises(ValueError, match="sentinel_latent_channels=5"):
+        _run_family(root, trace=0)
 
 
 def test_unknown_cell_is_refused():

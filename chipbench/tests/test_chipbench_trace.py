@@ -10,6 +10,7 @@ import pytest
 import chipbench_paths  # noqa: F401  (puts the harness on sys.path)
 
 from lib import flops, profile, readers
+from references import autoencoderkl as KL
 
 
 @pytest.fixture(scope="module")
@@ -88,15 +89,14 @@ OUT = ('%output_epilogue.3 = u8[128,16,1024,3]{3,2,1,0:T(8,128)(4,1)} '
 
 
 def test_kernel_events_match_decoder_calls():
-    calls = flops.decoder_calls(SD35)
+    calls = KL.calls(SD35)
     assert profile.kind_of(CONV) == "gn_silu_conv3x3"
     assert profile.kind_of("%fusion.12 = f32[2] fusion(f32[2] %a)") == "fusion"
     assert profile.kind_of("%copy-start = (f32[2]) copy-start()") == \
         "copy-start"
     call, n = readers._match(calls, "gn_silu_conv3x3", CONV)
     assert (call.h, call.cin, call.cout, n) == (128, 512, 512, 2)
-    call, n = readers._match(flops.decoder_calls(SD15), "upsample_conv3x3",
-                             UPS)
+    call, n = readers._match(KL.calls(SD15), "upsample_conv3x3", UPS)
     assert (call.h, call.cin, call.cout, n) == (256, 256, 256, 2)
     assert call.what == "up2.upsample"
     assert readers._match(calls, "upsample_conv3x3", UPS) is None
@@ -112,7 +112,7 @@ STATS = ('%gn_silu_conv3x3.6 = (f32[2,1,256]{2,1,0:T(1,128)S(1)}, '
 def test_roofline_never_passes_the_least_time():
     """A kernel event that took exactly its least time reads 100%."""
     import types
-    calls = flops.decoder_calls(SD35)
+    calls = KL.calls(SD35)
     call, n = readers._match(calls, "gn_silu_conv3x3", CONV)
     least = flops.least_seconds(call, n, 197e12, 819e9)
     op = profile.Op(CONV, 0.0, least * 1e9)
