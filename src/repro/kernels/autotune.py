@@ -62,7 +62,12 @@ DEFAULTS = {
     "gn_silu_conv3x3": {"rows": 32, "block_cout": 128},
     "upsample_conv3x3": {"rows": 16, "block_cout": 128},
     "output_epilogue": {"rows": 32, "block_cout": 128},
+    "rms_output_epilogue": {"rows": 32, "block_cout": 128},
 }
+
+#: The banded-conv kernels whose band carries the fused GroupNorm scratch
+#: (sized into their VMEM plan).
+GN_FUSED = ("gn_silu_conv3x3", "output_epilogue")
 
 _ROWS_GRID = (8, 16, 32, 64)
 _BLOCK_COUT_GRID = (32, 64, 128, 256)
@@ -191,7 +196,12 @@ def decode_shapes(cfg, latent_hwc: Tuple[int, int, int],
     """The deduplicated ``(kernel, call shape)`` set of one ``decode_u8``
     at batch size ``bucket`` — derived from the decoder architecture, not
     traced, so it can run before any compile.  ``cfg`` is a
-    :class:`repro.vae.model.VAEConfig`."""
+    :class:`repro.vae.model.VAEConfig`; a DC-AE decoder is refused (its
+    decode runs other kernels, and none of them is tuned)."""
+    from repro.vae.dcae import DCAEConfig
+    if isinstance(cfg, DCAEConfig):
+        raise ValueError(f"decode_shapes: {cfg.name!r} is a DC-AE decoder; "
+                         f"the walk covers AutoencoderKL decoders only")
     h, w, c_lat = (int(v) for v in latent_hwc)
     n = int(bucket)
     chs = cfg.block_out_channels
@@ -233,7 +243,7 @@ def _effective(kernel: str, spec: Dict[str, Any], rows: int,
     h, w, cin, cout = spec["h"], spec["w"], spec["cin"], spec["cout"]
     if kernel != "upsample_conv3x3":
         return plan((1, h, w, cin), cout, itemsize, rows, block_cout,
-                    kernel != "conv3x3")[:2]
+                    kernel in GN_FUSED)[:2]
     tc = cout_tile(cout, block_cout)
     return band_rows(h, w, cin, itemsize, rows, tc=tc, taps=8,
                      out_factor=2), tc

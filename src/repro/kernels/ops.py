@@ -139,7 +139,7 @@ def _note_path(kernel: str, x, wq, tuned) -> None:
     from repro.kernels import conv3x3 as c3
     knobs = tuned or autotune.DEFAULTS[kernel]
     path = c3.plan(x.shape, wq.shape[-1], x.dtype.itemsize, knobs["rows"],
-                   knobs["block_cout"], kernel != "conv3x3")[2]
+                   knobs["block_cout"], kernel in autotune.GN_FUSED)[2]
     c3.PATHS[path] += 1
 
 
@@ -208,6 +208,39 @@ def output_epilogue(x, scale, bias, w, b=None, groups: int = 32,
     return oe.output_epilogue(x, scale, bias, wq, b, groups=groups, eps=eps,
                               w_scale=w_scale,
                               interpret=impl == "pallas_interpret", **tuned)
+
+
+def rms_output_epilogue(x, scale, bias, w, b=None, eps: float = 1e-5,
+                        impl: Optional[str] = None):
+    """RMSNorm + ReLU + conv_out + clamp + uint8 quantize — the DC-AE
+    decode's final stage, returning displayable uint8 HWC pixels."""
+    impl = _resolve(impl, "rms_output_epilogue")
+    if impl == "xla":
+        return ref.rms_output_epilogue_ref(x, scale, bias, _dequant(w), b,
+                                           eps)
+    from repro.kernels import output_epilogue as oe
+    wq, w_scale = _weight_parts(w)
+    tuned = autotune.tuned_params("rms_output_epilogue", x.shape,
+                                  wq.shape[-1], weight_dtype_of(w))
+    _note_path("rms_output_epilogue", x, wq, tuned)
+    return oe.rms_output_epilogue(x, scale, bias, wq, b, eps=eps,
+                                  w_scale=w_scale,
+                                  interpret=impl == "pallas_interpret",
+                                  **tuned)
+
+
+def linear_attention(sources, head_dim: int, eps: float = 1e-15,
+                     impl: Optional[str] = None):
+    """ReLU linear attention over packed part-major ``[N, T, 3 * Cq]``
+    sources -> ``[N, T, S * Cq]`` (:mod:`repro.kernels.linear_attention`)."""
+    impl = _resolve(impl, "linear_attention")
+    sources = tuple(sources)
+    if impl == "xla":
+        return ref.linear_attention_ref(sources, head_dim, eps)
+    from repro.kernels import linear_attention as la
+    la.TRACED["linear_attention"] += 1        # per call site, at trace time
+    return la.linear_attention(sources, head_dim=head_dim, eps=eps,
+                               interpret=impl == "pallas_interpret")
 
 
 def flash_attention(q, k, v, causal: bool = False, scale=None,

@@ -14,6 +14,9 @@ Quantization is the paper's display mapping ``round((clip(y, -1, 1) + 1)
 * 127.5)`` computed in fp32 — identical on the oracle and the kernel, so
 the two can only differ where the conv accumulation itself differs
 (tests bound that at +-1 LSB).
+
+:func:`rms_output_epilogue` is the DC-AE decode's counterpart: RMSNorm +
+ReLU ahead of the same banded conv with the same uint8 epilogue.
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.kernels.conv3x3 import banded_conv
 from repro.kernels.gn_silu import gn_coefficients
+from repro.kernels.ref import rms_norm_ref
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "rows",
@@ -46,3 +50,20 @@ def output_epilogue(x: jax.Array, scale: jax.Array, bias: jax.Array,
                        interpret=interpret, w_scale=w_scale,
                        gn=(mean_c, mul_c, bias.astype(jnp.float32)),
                        quantize=True)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "rows", "block_cout",
+                                             "interpret"))
+def rms_output_epilogue(x: jax.Array, scale: jax.Array, bias: jax.Array,
+                        w: jax.Array, b: Optional[jax.Array] = None,
+                        eps: float = 1e-5, rows: int = 32,
+                        block_cout: int = 128, interpret: bool = False,
+                        w_scale: Optional[jax.Array] = None) -> jax.Array:
+    """``quantize_u8(conv3x3(relu(rms_norm(x))))``: the DC-AE decode's
+    output path.  The per-pixel RMSNorm (fp32 statistics over channels,
+    affine bias) and ReLU run as one XLA fusion ahead of the banded conv,
+    whose uint8 epilogue writes the served pixels.  x [N, H, W, Cin],
+    scale/bias [Cin], w [3, 3, Cin, Cout] -> uint8 [N, H, W, Cout]."""
+    y = jnp.maximum(rms_norm_ref(x, scale, bias, eps), 0.0)
+    return banded_conv(y, w, b, rows=rows, block_cout=block_cout,
+                       interpret=interpret, w_scale=w_scale, quantize=True)

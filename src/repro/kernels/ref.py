@@ -58,6 +58,47 @@ def output_epilogue_ref(x: jax.Array, scale: jax.Array, bias: jax.Array,
                                                groups, eps))
 
 
+def rms_norm_ref(x: jax.Array, scale: jax.Array, bias: jax.Array,
+                 eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over channels (NHWC last axis) with an affine bias, fp32
+    statistics: ``x * rsqrt(mean(x^2) + eps) * scale + bias``."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def rms_output_epilogue_ref(x: jax.Array, scale: jax.Array,
+                            bias: jax.Array, w: jax.Array,
+                            b: Optional[jax.Array] = None,
+                            eps: float = 1e-5) -> jax.Array:
+    """``quantize_u8(conv3x3(relu(rms_norm(x))))`` — oracle for the DC-AE
+    decode's uint8 output path."""
+    y = jnp.maximum(rms_norm_ref(x, scale, bias, eps), 0.0)
+    return quantize_u8_ref(conv3x3_ref(y, w, b))
+
+
+def linear_attention_ref(sources, head_dim: int,
+                         eps: float = 1e-15) -> jax.Array:
+    """ReLU linear attention over packed part-major sources (see
+    :mod:`repro.kernels.linear_attention`): each ``[N, T, 3 * Cq]`` source
+    holds every head's q, then k, then v, ``head_dim`` channels a head;
+    the result concatenates the sources' heads, ``[N, T, S * Cq]``."""
+    hi = jax.lax.Precision.HIGHEST
+    outs = []
+    for x in sources:
+        n, t, c3 = x.shape
+        q, k, v = (jnp.maximum(a, 0.0) if i < 2 else a
+                   for i, a in enumerate(
+                       x.astype(jnp.float32).reshape(n, t, 3, -1, head_dim)
+                       .transpose(2, 0, 1, 3, 4)))
+        kv = jnp.einsum("nthd,nthe->nhde", v, k, precision=hi)
+        num = jnp.einsum("nthe,nhde->nthd", q, kv, precision=hi)
+        den = jnp.einsum("nthe,nhe->nth", q, k.sum(axis=1), precision=hi)
+        outs.append((num / (den[..., None] + eps)).reshape(n, t, c3 // 3))
+    return jnp.concatenate(outs, axis=-1).astype(sources[0].dtype)
+
+
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = False,
                         scale: Optional[float] = None,

@@ -37,9 +37,9 @@ The read path records ``lb.*`` spans (``jax.profiler.TraceAnnotation``)
 where its work happens: ``lb.serve_window`` around a call, ``lb.lookup``
 per request (``lb.fetch`` for its durable read), ``lb.flush`` around the
 batched decode with ``lb.assemble`` (``lb.decompress``, ``lb.warm_up``
-with the banded convs its compile traced per kernel path,
-``lb.place``), ``lb.dispatch`` and ``lb.collect`` per chunk, and
-``lb.writeback`` after it.  Spans of one call share the ``call`` stat,
+with the banded convs its compile traced per kernel path and its
+linear-attention kernel calls, ``lb.place``), ``lb.dispatch`` and
+``lb.collect`` per chunk, and ``lb.writeback`` after it.  Spans of one call share the ``call`` stat,
 those of one request its ``oid``.  They land in a profiler trace, on the
 device's clock, only while one is being recorded
 (``jax.profiler.start_trace``); otherwise each costs about a microsecond.
@@ -63,6 +63,7 @@ from repro.core.regen_tier import Recipe, RegenTierStore, synthesize_image
 from repro.core.router import parse_node_index
 from repro.core.tuner import MarginalHitTuner, TunerConfig
 from repro.kernels.conv3x3 import PATHS as conv_paths
+from repro.kernels.linear_attention import TRACED as linattn_calls
 from repro.store.api import StoreConfig
 from repro.store.tiers import DurableTier, RecipeTier
 from repro.store.walk import TierWalk
@@ -270,14 +271,17 @@ class DecodeBatcher:
             return 0.0
         with TraceAnnotation("lb.warm_up", bucket=key[0]) as span:
             t0 = time.perf_counter()
-            before = dict(conv_paths)
+            counters = (conv_paths, linattn_calls)
+            before = [dict(c) for c in counters]
             z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
             np.asarray(self._decode_fn(z))
             self._warm.add(key)
-            # banded convs this compile traced on each path (0 and 0 when
-            # the decode's trace was already cached)
-            span.set_metadata(**{k: conv_paths[k] - before[k]
-                                 for k in conv_paths})
+            # banded convs this compile traced on each path, and linear-
+            # attention kernel calls (all 0 when the decode's trace was
+            # already cached)
+            span.set_metadata(**{k: c[k] - b[k]
+                                 for c, b in zip(counters, before)
+                                 for k in c})
             return time.perf_counter() - t0
 
     def prewarm(self, latent_hwc: Tuple[int, int, int]) -> Dict[int, float]:

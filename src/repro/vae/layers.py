@@ -5,6 +5,7 @@ kernels and the XLA reference path are interchangeable (``impl=`` flag).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -12,6 +13,18 @@ import jax
 import jax.numpy as jnp
 
 Params = Dict[str, Any]
+
+
+def fp32_math(fn):
+    """Trace ``fn`` with fp32 matmul/conv precision.  On TPU the default
+    precision of an fp32 dot is one bf16 pass; the decode's XLA ops (1x1
+    shortcut convs, attention projections) would then drift from the fp32
+    oracle by more than the ±1-LSB contract.  A no-op on CPU."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 # ---------------------------------------------------------------------------

@@ -6,19 +6,24 @@ spatial resolution, block_out_channels (128, 256, 512, 512), 3 res blocks
 per decoder level, one single-head attention mid-block — ~49.5 M params at
 defaults, as in paper Table 1b.  The decode is a deterministic feed-forward
 pass: same latent -> bit-identical pixels on a fixed stack.
+
+:class:`VAE` also serves the DC-AE family (:mod:`repro.vae.dcae`): a
+:class:`~repro.vae.dcae.DCAEConfig` in :data:`CONFIGS` selects its init and
+decode (:func:`_family`).
 """
 
 from __future__ import annotations
 
 import copy
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.vae import dcae
 from repro.vae import layers as L
+from repro.vae.dcae import DCAE_F32C32, DCAEConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +58,10 @@ DEMO_VAE = VAEConfig(name="demo", latent_channels=4,
                      groups=4)
 
 
-#: Decoder configurations by name (the launcher's ``--decoder``).
-CONFIGS = {c.name: c for c in (SD35_VAE, FLUX_VAE, SD15_VAE, DEMO_VAE)}
+#: Decoder configurations by name (the launcher's ``--decoder``): the
+#: AutoencoderKL family and DC-AE f32c32 (:mod:`repro.vae.dcae`).
+CONFIGS = {c.name: c for c in (SD35_VAE, FLUX_VAE, SD15_VAE, DEMO_VAE,
+                               DCAE_F32C32)}
 
 
 def calibrated_vae(name: str = "demo", seed: int = 0,
@@ -150,16 +157,7 @@ def init_encoder(key, cfg: VAEConfig) -> Dict[str, Any]:
 # forward passes
 # ---------------------------------------------------------------------------
 
-def _fp32_math(fn):
-    """Trace ``fn`` with fp32 matmul/conv precision.  On TPU the default
-    precision of an fp32 dot is one bf16 pass; the decode's XLA ops (1x1
-    shortcut convs, attention projections) would then drift from the fp32
-    oracle by more than the ±1-LSB contract.  A no-op on CPU."""
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        with jax.default_matmul_precision("highest"):
-            return fn(*args, **kwargs)
-    return wrapped
+_fp32_math = L.fp32_math
 
 
 def _decode_trunk(params: Dict[str, Any], z: jax.Array, cfg: VAEConfig,
@@ -231,6 +229,16 @@ def param_count(params) -> int:
     return sum(p.size for p in jax.tree_util.tree_leaves(params))
 
 
+def _family(cfg):
+    """(init_decoder, init_encoder, decode, decode_u8, encode) of the
+    decoder family ``cfg`` belongs to; the encoder entries are None for a
+    family whose encoder the program does not carry (DC-AE: objects are
+    put as latents)."""
+    if isinstance(cfg, DCAEConfig):
+        return dcae.init_decoder, None, dcae.decode, dcae.decode_u8, None
+    return init_decoder, init_encoder, decode, decode_u8, encode
+
+
 class VAE:
     """Convenience wrapper bundling config + params + jitted entry points.
 
@@ -251,21 +259,23 @@ class VAE:
                  weight_dtype: str = "float32"):
         self.cfg = cfg
         self.impl = impl          # None -> chosen from the platform (ops)
+        init_dec, init_enc, dec, dec_u8, enc = _family(cfg)
         key = jax.random.PRNGKey(seed)
         kd, ke = jax.random.split(key)
         # one jitted init per tree: on an accelerator, eager init would
         # compile every distinct parameter shape separately
-        self.decoder = jax.jit(init_decoder, static_argnums=1)(kd, cfg)
-        self.encoder = (jax.jit(init_encoder, static_argnums=1)(ke, cfg)
-                        if with_encoder else None)
+        self.decoder = jax.jit(init_dec, static_argnums=1)(kd, cfg)
+        self.encoder = (jax.jit(init_enc, static_argnums=1)(ke, cfg)
+                        if with_encoder and init_enc is not None else None)
         self.weight_dtype = "float32"
         self._qparams: Dict[str, Any] = {}
         self.device = None
-        self._decode = jax.jit(lambda p, z: decode(p, z, cfg, impl))
+        self._decode = jax.jit(lambda p, z: dec(p, z, cfg, impl))
         # no input donation: the uint8 output cannot alias the float32
         # latent batch, so XLA reports a donated batch as unusable
-        self._decode_u8 = jax.jit(lambda p, z: decode_u8(p, z, cfg, impl))
-        self._encode = jax.jit(lambda p, x: encode(p, x, cfg, impl))
+        self._decode_u8 = jax.jit(lambda p, z: dec_u8(p, z, cfg, impl))
+        self._encode = (None if enc is None else
+                        jax.jit(lambda p, x: enc(p, x, cfg, impl)))
         if weight_dtype != "float32":
             self.set_weight_dtype(weight_dtype)
 
@@ -334,6 +344,9 @@ class VAE:
                 clear()
 
     def encode_mean(self, x: jax.Array) -> jax.Array:
+        if self.encoder is None:
+            raise ValueError(f"decoder {self.cfg.name!r} has no encoder "
+                             f"here: put latents, not pixels")
         return self._encode(self.encoder, self.place(x))[0]
 
     @property

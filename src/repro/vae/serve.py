@@ -20,6 +20,15 @@ from repro.configs.shapes import ShapeSpec
 from repro.vae.model import SD35_VAE, VAEConfig, decode
 
 
+def _kl_only(cfg, what: str) -> None:
+    """The analytic walkers below follow the AutoencoderKL decoder; refuse
+    any other family by name rather than count it as one."""
+    if not isinstance(cfg, VAEConfig):
+        raise ValueError(f"{what}: {cfg.name!r} is not an AutoencoderKL "
+                         f"decoder; its FLOPs and bytes are counted by its "
+                         f"benchmark family module")
+
+
 def make_decode_step(cfg: VAEConfig, mesh=None):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -44,6 +53,7 @@ def decoder_flops_per_image(cfg: VAEConfig = SD35_VAE,
     2x2 taps on the *pre-upsample* grid — 16 tap-matmul units vs 36 for a
     3x3 conv over the 4x upsampled tensor (2.25x fewer MACs), which
     ``fused_upsampler=True`` (the shipped decode path) accounts for."""
+    _kl_only(cfg, "decoder_flops_per_image")
     lat = resolution // cfg.spatial_factor
     chs = list(reversed(cfg.block_out_channels))     # top -> bottom
     top = chs[0]
@@ -95,6 +105,7 @@ def decoder_bytes_per_image(cfg: VAEConfig = SD35_VAE,
     ``uint8_output=True`` models the fused output epilogue: the final
     image leaves as 1-byte pixels instead of ``dtype_size`` floats.
     """
+    _kl_only(cfg, "decoder_bytes_per_image")
     lat = resolution // cfg.spatial_factor
     chs = list(reversed(cfg.block_out_channels))
     params = 49.55e6

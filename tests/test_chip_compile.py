@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.conv3x3 import conv3x3
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gn_silu_conv import gn_silu_conv3x3
-from repro.kernels.output_epilogue import output_epilogue
+from repro.kernels.linear_attention import linear_attention
+from repro.kernels.output_epilogue import output_epilogue, rms_output_epilogue
 from repro.kernels.upsample_conv import upsample_conv3x3
 
 
@@ -121,3 +122,23 @@ def test_flash_attention_mid_block(one_chip):
     """The mid-block attention: one head over 128x128 = 16,384 tokens."""
     compile_for(one_chip, lambda q, k, v: flash_attention(q, k, v),
                 (1, 1, 16384, 512), (1, 1, 16384, 512), (1, 1, 16384, 512))
+
+
+def test_linear_attention_dcae_128_512(one_chip):
+    """DC-AE's widest linear attention: 128x128 tokens, 512 channels, two
+    packed sources of 3 x 512 (the projections and their multiscale
+    aggregate), 32 heads of 32 in all; the result keeps the ``[N, T, 2C]``
+    shape the benchmark's reader matches."""
+    c = compile_for(one_chip, lambda a, b: linear_attention((a, b), 32),
+                    (1, 16384, 1536), (1, 16384, 1536))
+    assert c.out_info.shape == (1, 16384, 1024)
+
+
+def test_rms_output_epilogue_dcae_1024(one_chip):
+    """DC-AE's output path: RMSNorm + ReLU + conv_out 128 -> 3 + uint8 at
+    1024x1024."""
+    c = compile_for(one_chip,
+                    lambda x, s, g, w, b: rms_output_epilogue(x, s, g, w, b),
+                    (1, 1024, 1024, 128), (128,), (128,), (3, 3, 128, 3),
+                    (3,))
+    assert c.out_info.dtype == jnp.uint8

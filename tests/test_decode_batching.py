@@ -209,8 +209,8 @@ def test_warm_up_span_counts_conv_paths(monkeypatch, latent_hw, winograd,
                                         direct):
     """A warm-up's ``lb.warm_up`` span carries the banded convs its
     compile traced on each path: the demo decoder has 14 (conv_in, two
-    in each of six res blocks, the output epilogue); a warm bucket opens
-    no second span."""
+    in each of six res blocks, the output epilogue), and no
+    linear-attention call; a warm bucket opens no second span."""
     from repro.serve import engine
     from repro.vae.model import DEMO_VAE
     spans = _Spans()
@@ -222,4 +222,4 @@ def test_warm_up_span_counts_conv_paths(monkeypatch, latent_hw, winograd,
     batcher.prewarm(shape)
     warm = [s for name, s in spans.opened if name == "lb.warm_up"]
     assert warm == [{"bucket": 1, "winograd_rows": winograd,
-                     "direct": direct}]
+                     "direct": direct, "linear_attention": 0}]
