@@ -27,6 +27,19 @@ uint8 output epilogue (:mod:`repro.kernels.output_epilogue`,
 ``quantize=True``).  Band height is sized against the whole per-step VMEM
 footprint (:func:`step_vmem_bytes`), and the kernel asks Mosaic for
 :data:`VMEM_LIMIT` of scoped VMEM.
+
+Two more epilogues serve the DC-AE ResBlock ``x + RMSNorm(conv2(SiLU(
+conv1(x))))`` (:func:`repro.vae.dcae.res_block`), so neither its SiLU nor
+its RMSNorm and residual take a pass of their own over HBM:
+
+* ``act="silu"`` writes ``y * sigmoid(y)`` after the bias (``silu(0) =
+  0``, so the next conv's zero SAME ring holds);
+* ``rms=(scale, bias)`` with ``residual`` writes ``residual + (y *
+  rsqrt(mean_c(y^2) + eps)) * scale + bias``, fp32 statistics over every
+  output channel.  The output block then spans all of Cout and stays in
+  VMEM across the inner Cout-tile grid axis: each tile step stores its
+  lane slice, and the last one normalizes each row of the block in place
+  and adds the residual block, indexed like the output.
 """
 
 from __future__ import annotations
@@ -59,18 +72,23 @@ def _pad(v: int, m: int) -> int:
 
 def step_vmem_bytes(rows: int, width: int, cin: int, itemsize: int,
                     tc: int = 128, taps: int = 9, out_factor: int = 1,
-                    fused_gn: bool = False) -> int:
+                    fused_gn: bool = False, rms_cout: int = 0) -> int:
     """VMEM one grid step holds: the double-buffered input band (lanes
     padded to 128, sublanes to 8), the fp32 normalized band scratch of
     the fused GN kernels, the double-buffered weight tile and the
     double-buffered fp32 output block (``out_factor`` output rows per
-    band row: 2 for the upsampler's two row phases)."""
+    band row: 2 for the upsampler's two row phases).  With the fused
+    RMSNorm (``rms_cout`` output channels) the output block spans every
+    channel and a residual block of its size is double-buffered too."""
     band = (rows + 2) * _pad(width + 2, 8) * _pad(cin, 128)
-    out = out_factor * rows * _pad(width, 8) * _pad(tc, 128) * 4
+    out = (out_factor * rows * _pad(width, 8)
+           * _pad(rms_cout or tc, 128) * 4)
     wts = taps * _pad(cin, 8) * _pad(tc, 128) * 4
     total = 2 * band * itemsize + 2 * wts + 2 * out
     if fused_gn:
         total += band * 4
+    if rms_cout:
+        total += 2 * out
     return total
 
 
@@ -105,16 +123,22 @@ WINOGRAD_TAPS = 12
 #: span).
 PATHS: Dict[str, int] = {"winograd_rows": 0, "direct": 0}
 
+#: Banded convs traced with each fused epilogue (``act="silu"``; ``rms=``
+#: with ``residual=``), counted and reported like :data:`PATHS`.
+EPILOGUES: Dict[str, int] = {"silu": 0, "rms_residual": 0}
+
 
 def plan(x_shape: Tuple[int, ...], cout: int, itemsize: int, rows: int,
-         block_cout: int, fused_gn: bool) -> Tuple[int, int, str]:
+         block_cout: int, fused_gn: bool,
+         fused_rms: bool = False) -> Tuple[int, int, str]:
     """(band rows, Cout tile, path) of one banded conv of ``x_shape``
     ``[N, H, W, Cin]``: an even band takes F(2,3) along rows
     (``'winograd_rows'``), an odd one the nine-tap loop (``'direct'``)."""
     _, h, width, cin = x_shape
     tc = cout_tile(cout, block_cout)
     rows = band_rows(h, width, cin, itemsize, rows, tc=tc,
-                     taps=WINOGRAD_TAPS, fused_gn=fused_gn)
+                     taps=WINOGRAD_TAPS, fused_gn=fused_gn,
+                     rms_cout=cout if fused_rms else 0)
     return rows, tc, "winograd_rows" if rows % 2 == 0 else "direct"
 
 
@@ -157,14 +181,18 @@ def quantize_u8(y: jax.Array) -> jax.Array:
 
 
 def _conv_kernel(*refs, rows: int, width: int, nb: int, fused_gn: bool,
-                 has_scale: bool, quantize: bool, winograd: bool):
+                 has_scale: bool, quantize: bool, winograd: bool,
+                 act: Optional[str], rms_eps: Optional[float]):
     it = iter(refs)
     x_ref = next(it)                                 # [1, rows+2, W+2, Cin]
     if fused_gn:
         mean_ref, mul_ref, gbias_ref = next(it), next(it), next(it)
     w_ref = next(it)                     # [4, 3, Cin, tc] or [3, 3, Cin, tc]
     s_ref = next(it) if has_scale else None          # [1, tc]
-    b_ref, o_ref = next(it), next(it)                # [1, tc], [1, rows, W, tc]
+    b_ref = next(it)                                 # [1, tc]
+    if rms_eps is not None:
+        g_ref, gb_ref, res_ref = next(it), next(it), next(it)
+    o_ref = next(it)             # [1, rows, W, tc] ([1, rows, W, Cout]: rms)
 
     if fused_gn:
         ybuf = next(it)                              # [rows+2, W+2, Cin] f32
@@ -202,13 +230,50 @@ def _conv_kernel(*refs, rows: int, width: int, nb: int, fused_gn: bool,
     bias = b_ref[...].astype(jnp.float32)
     scale = None if s_ref is None else s_ref[...].astype(jnp.float32)
 
-    def store(r, acc):
+    def epilogue(acc):
         if scale is not None:
             # scale is per output channel, so one fp32 multiply of the
             # summed accumulator dequantizes every tap exactly
             acc = acc * scale
         y = acc + bias
-        o_ref[0, r] = quantize_u8(y) if quantize else y.astype(o_ref.dtype)
+        return y * jax.nn.sigmoid(y) if act == "silu" else y
+
+    if rms_eps is None:
+        def store(r, acc):
+            y = epilogue(acc)
+            o_ref[0, r] = (quantize_u8(y) if quantize
+                           else y.astype(o_ref.dtype))
+    else:
+        cout = o_ref.shape[-1]
+        tc = b_ref.shape[-1]
+        nt = cout // tc
+        lanes = [slice(t * tc, (t + 1) * tc) for t in range(nt)]
+        tile = pl.program_id(1)
+
+        def normalize(r, y):
+            # the last tile: the row's earlier tiles are in the resident
+            # output block, so the statistics see every channel
+            ss = jnp.sum(y * y, axis=-1, keepdims=True)
+            for t in range(nt - 1):
+                p = o_ref[0, r, :, lanes[t]].astype(jnp.float32)
+                ss = ss + jnp.sum(p * p, axis=-1, keepdims=True)
+            inv = jax.lax.rsqrt(ss / cout + rms_eps)
+            for t in range(nt):
+                p = (y if t == nt - 1
+                     else o_ref[0, r, :, lanes[t]].astype(jnp.float32))
+                v = ((p * inv) * g_ref[:, lanes[t]].astype(jnp.float32)
+                     + gb_ref[:, lanes[t]].astype(jnp.float32))
+                o_ref[0, r, :, lanes[t]] = (
+                    res_ref[0, r, :, lanes[t]].astype(jnp.float32)
+                    + v).astype(o_ref.dtype)
+
+        def store(r, acc):
+            y = epilogue(acc)
+            for t in range(nt - 1):
+                @pl.when(tile == t)
+                def _keep(t=t):
+                    o_ref[0, r, :, lanes[t]] = y.astype(o_ref.dtype)
+            pl.when(tile == nt - 1)(lambda: normalize(r, y))
 
     def conv_row(r, carry):
         acc = None
@@ -243,20 +308,34 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
                 rows: int, block_cout: int, interpret: bool,
                 w_scale: Optional[jax.Array] = None,
                 gn: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
-                quantize: bool = False) -> jax.Array:
+                quantize: bool = False, act: Optional[str] = None,
+                rms: Optional[Tuple[jax.Array, jax.Array]] = None,
+                residual: Optional[jax.Array] = None,
+                eps: float = 1e-5) -> jax.Array:
     """x [N, H, W, Cin], w [3, 3, Cin, Cout] -> [N, H, W, Cout] (SAME).
 
     ``gn = (mean_c, mul_c, bias_c)`` normalizes + SiLU-activates the band
     in VMEM first (per-image ``[N, 1, Cin]`` mean and ``inv_std * scale``
     from :func:`repro.kernels.gn_silu.gn_coefficients`, ``[Cin]`` bias);
     ``quantize`` emits uint8 display pixels instead of ``x.dtype``.
-    The path follows the band's height (:func:`plan`)."""
+    After the bias (and the int8 scale ahead of it), ``act="silu"``
+    applies SiLU, and ``rms = (scale, bias)`` ``[Cout]`` with ``residual``
+    ``[N, H, W, Cout]`` writes ``residual + rms_norm(y)`` (``eps`` inside
+    the root).  The path follows the band's height (:func:`plan`)."""
     n, h, width, cin = x.shape
     cout = w.shape[-1]
+    if act not in (None, "silu"):
+        raise ValueError(f"banded_conv: act must be None or 'silu', "
+                         f"not {act!r}")
+    if (rms is None) != (residual is None) or (rms is not None
+                                               and quantize):
+        raise ValueError("banded_conv: rms= needs residual= (and no "
+                         "quantize)")
     if b is None:
         b = jnp.zeros((cout,), x.dtype)
+    rms_eps = None if rms is None else float(eps)
     rows, tc, path = plan(x.shape, cout, x.dtype.itemsize, rows, block_cout,
-                          gn is not None)
+                          gn is not None, rms is not None)
     winograd = path == "winograd_rows"
     if winograd:
         w = winograd_weights(w)                      # [4, 3, Cin, Cout]
@@ -281,16 +360,31 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
         operands.append(w_scale.reshape(1, cout))
     in_specs.append(pl.BlockSpec((1, tc), lambda i, c: (0, c)))
     operands.append(b.reshape(1, cout))
+    if rms is None:
+        out_spec = pl.BlockSpec((1, rows, width, tc),
+                                lambda i, c: (i, 0, 0, c))
+    else:
+        # every channel of the band's rows, resident across the Cout tiles
+        out_spec = pl.BlockSpec((1, rows, width, cout),
+                                lambda i, c: (i, 0, 0, 0))
+        in_specs += [pl.BlockSpec((1, cout), lambda i, c: (0, 0)),
+                     pl.BlockSpec((1, cout), lambda i, c: (0, 0)),
+                     pl.BlockSpec((1, rows, width, cout),
+                                  lambda i, c: (i, 0, 0, 0))]
+        # the residual in the output's band shape: given as [N, H, W, C],
+        # its layout constraint made XLA copy the next conv's staged bands
+        # twice at batch > 1 (3.5 GiB more temporaries at DC-AE bucket 4)
+        operands += [rms[0].reshape(1, cout), rms[1].reshape(1, cout),
+                     residual.reshape(n * nb, rows, width, cout)]
 
     out = pl.pallas_call(
         functools.partial(_conv_kernel, rows=rows, width=width, nb=nb,
                           fused_gn=gn is not None,
                           has_scale=w_scale is not None, quantize=quantize,
-                          winograd=winograd),
+                          winograd=winograd, act=act, rms_eps=rms_eps),
         grid=(n * nb, cout // tc),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, width, tc),
-                               lambda i, c: (i, 0, 0, c)),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n * nb, rows, width, cout),
             jnp.uint8 if quantize else x.dtype),
@@ -301,17 +395,23 @@ def banded_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array], *,
     return out.reshape(n, h, width, cout)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "block_cout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rows", "block_cout",
+                                             "interpret", "act", "eps"))
 def conv3x3(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
             rows: int = 32, block_cout: int = 128,
             interpret: bool = False,
-            w_scale: Optional[jax.Array] = None) -> jax.Array:
+            w_scale: Optional[jax.Array] = None, act: Optional[str] = None,
+            rms: Optional[Tuple[jax.Array, jax.Array]] = None,
+            residual: Optional[jax.Array] = None,
+            eps: float = 1e-5) -> jax.Array:
     """x [N, H, W, Cin], w [3, 3, Cin, Cout] -> [N, H, W, Cout] (SAME).
 
     ``w`` may be stored float32/bfloat16 or int8 with ``w_scale`` [Cout]
     — the per-channel dequant then happens on the accumulator in VMEM.  An
     even band takes the fp32 row-transformed filter
     (:func:`winograd_weights`, int8 taps in integer units); an odd band
-    casts each tap tile to fp32 in VMEM."""
+    casts each tap tile to fp32 in VMEM.  ``act``, ``rms``, ``residual``
+    and ``eps`` select the fused epilogues of :func:`banded_conv`."""
     return banded_conv(x, w, b, rows=rows, block_cout=block_cout,
-                       interpret=interpret, w_scale=w_scale)
+                       interpret=interpret, w_scale=w_scale, act=act,
+                       rms=rms, residual=residual, eps=eps)

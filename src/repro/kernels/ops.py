@@ -130,17 +130,23 @@ def _weight_parts(w):
     return w, None
 
 
-def _note_path(kernel: str, x, wq, tuned) -> None:
+def _note_path(kernel: str, x, wq, tuned, act: Optional[str] = None,
+               rms: bool = False) -> None:
     """Count the traced call site of a banded conv in
-    :data:`repro.kernels.conv3x3.PATHS` by the path its band takes.  Here,
+    :data:`repro.kernels.conv3x3.PATHS` by the path its band takes, and in
+    :data:`~repro.kernels.conv3x3.EPILOGUES` by its fused epilogue.  Here,
     at trace time of the caller: inside the jitted wrappers a Python side
     effect would run once per distinct shape, since JAX reuses an inner
     ``jit``'s trace, not once per conv of the decode."""
     from repro.kernels import conv3x3 as c3
     knobs = tuned or autotune.DEFAULTS[kernel]
     path = c3.plan(x.shape, wq.shape[-1], x.dtype.itemsize, knobs["rows"],
-                   knobs["block_cout"], kernel in autotune.GN_FUSED)[2]
+                   knobs["block_cout"], kernel in autotune.GN_FUSED, rms)[2]
     c3.PATHS[path] += 1
+    if act is not None:
+        c3.EPILOGUES[act] += 1
+    if rms:
+        c3.EPILOGUES["rms_residual"] += 1
 
 
 def _dequant(w, dtype=jnp.float32):
@@ -265,16 +271,23 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
                                interpret=impl == "pallas_interpret")
 
 
-def conv3x3(x, w, b=None, impl: Optional[str] = None):
+def conv3x3(x, w, b=None, act: Optional[str] = None, rms=None,
+            residual=None, eps: float = 1e-5, impl: Optional[str] = None):
+    """3x3 SAME conv; ``act="silu"`` fuses a SiLU after the bias, and
+    ``rms = (scale, bias)`` with ``residual`` fuses ``residual +
+    rms_norm(conv)`` (the DC-AE ResBlock's two convs,
+    :func:`repro.kernels.conv3x3.banded_conv`)."""
     impl = _resolve(impl, "conv3x3")
     if impl == "xla":
-        return ref.conv3x3_ref(x, _dequant(w), b)
+        return ref.conv3x3_epilogue_ref(x, _dequant(w), b, act, rms,
+                                        residual, eps)
     from repro.kernels import conv3x3 as c3
     wq, w_scale = _weight_parts(w)
     tuned = autotune.tuned_params("conv3x3", x.shape, wq.shape[-1],
                                   weight_dtype_of(w))
-    _note_path("conv3x3", x, wq, tuned)
-    return c3.conv3x3(x, wq, b, w_scale=w_scale,
+    _note_path("conv3x3", x, wq, tuned, act, rms is not None)
+    return c3.conv3x3(x, wq, b, w_scale=w_scale, act=act, rms=rms,
+                      residual=residual, eps=eps,
                       interpret=impl == "pallas_interpret", **tuned)
 
 

@@ -164,6 +164,22 @@ def conv3x3_ref(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None
     return y
 
 
+def conv3x3_epilogue_ref(x: jax.Array, w: jax.Array,
+                         b: Optional[jax.Array] = None,
+                         act: Optional[str] = None, rms=None,
+                         residual: Optional[jax.Array] = None,
+                         eps: float = 1e-5) -> jax.Array:
+    """``conv3x3`` with the banded kernel's fused epilogues: ``act="silu"``
+    gives ``silu(conv)``, ``rms = (scale, bias)`` gives ``residual +
+    rms_norm(conv)`` — oracle for the DC-AE ResBlock's two convs."""
+    y = conv3x3_ref(x, w, b)
+    if act == "silu":
+        y = y * jax.nn.sigmoid(y)
+    if rms is not None:
+        y = residual + rms_norm_ref(y, rms[0], rms[1], eps)
+    return y
+
+
 def rwkv6_scan_ref(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                    u: jax.Array, state: Optional[jax.Array] = None):
     """RWKV-6 linear-attention recurrence (per head), fp32 state.

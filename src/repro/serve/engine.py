@@ -62,6 +62,7 @@ from repro.core.latent_store import LatentStore
 from repro.core.regen_tier import Recipe, RegenTierStore, synthesize_image
 from repro.core.router import parse_node_index
 from repro.core.tuner import MarginalHitTuner, TunerConfig
+from repro.kernels.conv3x3 import EPILOGUES as conv_epilogues
 from repro.kernels.conv3x3 import PATHS as conv_paths
 from repro.kernels.linear_attention import TRACED as linattn_calls
 from repro.store.api import StoreConfig
@@ -271,14 +272,14 @@ class DecodeBatcher:
             return 0.0
         with TraceAnnotation("lb.warm_up", bucket=key[0]) as span:
             t0 = time.perf_counter()
-            counters = (conv_paths, linattn_calls)
+            counters = (conv_paths, conv_epilogues, linattn_calls)
             before = [dict(c) for c in counters]
             z = self.vae.place(np.zeros(key[:1] + key[1], np.float32))
             np.asarray(self._decode_fn(z))
             self._warm.add(key)
-            # banded convs this compile traced on each path, and linear-
-            # attention kernel calls (all 0 when the decode's trace was
-            # already cached)
+            # banded convs this compile traced on each path and with each
+            # fused epilogue, and linear-attention kernel calls (all 0
+            # when the decode's trace was already cached)
             span.set_metadata(**{k: c[k] - b[k]
                                  for c, b in zip(counters, before)
                                  for k in c})

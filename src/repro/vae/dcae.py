@@ -25,15 +25,18 @@ fewer durable bytes than SD3.5's 128x128x16.  The decode (diffusers
 * RMSNorm: over channels, ``x * rsqrt(mean(x^2) + 1e-5) * scale + bias``
   with fp32 statistics.
 
-Kernels: every 3x3 conv runs the banded conv (:func:`ops.conv3x3`), the
-up-block convs the fused upsampler (:func:`ops.upsample_conv3x3`), the
-attention core :func:`ops.linear_attention`, and the output path
+Kernels: every 3x3 conv runs the banded conv (:func:`ops.conv3x3`; a
+ResBlock's SiLU rides in conv1's epilogue, its RMSNorm and residual in
+conv2's), the up-block convs the fused upsampler
+(:func:`ops.upsample_conv3x3`), the attention core
+:func:`ops.linear_attention`, and the output path
 :func:`ops.rms_output_epilogue`; the 1x1 projections, the depthwise and
-grouped convs, the norms and the shortcuts are XLA.  The linear
-attention reads each source packed part-major (every head's q, then k,
-then v): the ``q|k|v`` projection columns and the aggregation's channels
-are permuted into that order at trace time (:func:`part_major`), which
-the per-channel depthwise and the 32-aligned grouped convs commute with.
+grouped convs, the EfficientViT norms and the shortcuts are XLA.  The
+linear attention reads each source packed part-major (every head's q,
+then k, then v): the ``q|k|v`` projection columns and the aggregation's
+channels are permuted into that order at trace time (:func:`part_major`),
+which the per-channel depthwise and the 32-aligned grouped convs commute
+with.
 Each stage runs under a ``jax.named_scope`` (``dcae.s<i>.up``,
 ``dcae.s<i>.res``, ``dcae.s<i>.evit``), so a profiler's view splits the
 decode by stage.
@@ -229,10 +232,14 @@ def part_major(channels: int, head_dim: int) -> np.ndarray:
 
 
 def res_block(x: jax.Array, p, impl: Optional[str] = None) -> jax.Array:
+    """``x + rms_norm(conv2(silu(conv1(x))))`` as two banded convs: the
+    SiLU in conv1's epilogue, the RMSNorm and residual in conv2's."""
     from repro.kernels import ops                     # late import (no cycle)
-    h = ops.conv3x3(x, p["conv1"]["w"], p["conv1"]["b"], impl=impl)
-    h = ops.conv3x3(silu(h), p["conv2"]["w"], None, impl=impl)
-    return x + rms_norm(h, p["norm"])
+    h = ops.conv3x3(x, p["conv1"]["w"], p["conv1"]["b"], act="silu",
+                    impl=impl)
+    return ops.conv3x3(h, p["conv2"]["w"], None,
+                       rms=(p["norm"]["scale"], p["norm"]["bias"]),
+                       residual=x, eps=RMS_EPS, impl=impl)
 
 
 def linear_attention_block(x: jax.Array, p, head_dim: int,

@@ -142,3 +142,127 @@ def test_rms_output_epilogue_dcae_1024(one_chip):
                     (1, 1024, 1024, 128), (128,), (128,), (3, 3, 128, 3),
                     (3,))
     assert c.out_info.dtype == jnp.uint8
+
+
+# -- the DC-AE ResBlock's two fused banded convs, at bucket 4
+
+#: (H = W, C, conv2's band rows) of the three ResBlock stages.
+RESBLOCK = [(1024, 128, 8), (512, 256, 8), (256, 512, 4)]
+
+
+@pytest.fixture(scope="module")
+def fused_conv(one_chip):
+    """The compiled fused conv of ``(epilogue, hw, c)``, compiled once for
+    the module's tests."""
+    compiled = {}
+
+    def get(epilogue, hw, c):
+        key = (epilogue, hw, c)
+        if key in compiled:
+            return compiled[key]
+        if epilogue == "silu":
+            compiled[key] = compile_for(
+                one_chip, lambda x, w, b: conv3x3(x, w, b, act="silu"),
+                (4, hw, hw, c), (3, 3, c, c), (c,))
+        else:
+            compiled[key] = compile_for(
+                one_chip, lambda x, w, g, gb: conv3x3(
+                    x, w, None, rms=(g, gb), residual=x),
+                (4, hw, hw, c), (3, 3, c, c), (c,), (c,))
+        return compiled[key]
+    return get
+
+
+def _events(compiled, kernel):
+    """The kernel's instructions as a TPU trace names its events: the
+    optimized HLO with every operand's shape in the text."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    (module,) = compiled.runtime_executable().hlo_modules()
+    return [ln.strip() for ln in module.to_string(opts).splitlines()
+            if re.match(rf"\s*%?{kernel}(\.\d+)? = ", ln)]
+
+
+def _shapes(text):
+    return [tuple(int(v) for v in m.split(",")) for m in _SHAPE.findall(text)]
+
+
+@pytest.mark.parametrize("epilogue", ["silu", "rms_residual"])
+@pytest.mark.parametrize("hw,c,rms_rows", RESBLOCK)
+def test_dcae_res_block_fused_conv(fused_conv, epilogue, hw, c, rms_rows):
+    """Each fused call is one ``conv3x3`` custom call with one array
+    result, the band ``[N * bands, rows+2, W+2, Cin]`` its first 4-D
+    operand (the residual comes after it), and an even band: F(2,3)."""
+    from repro.kernels.conv3x3 import plan
+    rows, _, path = plan((4, hw, hw, c), c, 4, 32, 128, False,
+                         epilogue == "rms_residual")
+    assert path == "winograd_rows" and rows % 2 == 0
+    if epilogue == "rms_residual":
+        assert rows == rms_rows
+    compiled = fused_conv(epilogue, hw, c)
+    assert compiled.out_info.shape == (4, hw, hw, c)
+    (event,) = _events(compiled, "conv3x3")
+    assert "tpu_custom_call" in event
+    assert re.match(r"%?conv3x3(\.\d+)? = f32\[", event)  # not a tuple
+    result, *operands = _shapes(event.split(", custom_call_target")[0])
+    assert result == (4 * hw // rows, rows, hw, c)
+    four_d = [s for s in operands if len(s) == 4]
+    assert four_d[0] == (4 * hw // rows, rows + 2, hw + 2, c)
+    if epilogue == "rms_residual":                   # the residual's rows
+        assert four_d[-1] == (4 * hw // rows, rows, hw, c)
+
+
+@pytest.fixture(scope="module")
+def conv_roofline_dcae():
+    """The benchmark's ``conv_roofline.dcae`` reader and the published
+    DC-AE configuration's call list, read from ``chipbench/``."""
+    import importlib.util
+    import json
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+
+    def load(rel, name):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(bench, rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    dc = load("references/autoencoderdc.py", "reference_autoencoderdc")
+    with open(os.path.join(bench, "configs", "dcae_f32c32-1024.json")) as f:
+        arch = json.load(f)["architecture"]
+    return (load("metrics/conv_roofline.dcae.py", "metric_conv_roofline_dcae"),
+            dc.calls(arch))
+
+
+def test_conv_roofline_dcae_reads_the_fused_convs(fused_conv,
+                                                  conv_roofline_dcae):
+    """The reader pairs every fused ResBlock conv event with its call of
+    ``autoencoderdc.calls`` (4 images each) and reads a share from them."""
+    import types
+    from lib.peaks import PEAKS
+    from lib.profile import kind_of
+    reader, calls = conv_roofline_dcae
+    events = []
+    for hw, c, _ in RESBLOCK:
+        for epilogue in ("silu", "rms_residual"):
+            (text,) = _events(fused_conv(epilogue, hw, c), "conv3x3")
+            assert kind_of(text) == "conv3x3"
+            call, images = reader.match(calls, "conv3x3", text)
+            assert (call.kernel, call.h, call.cin, call.cout) == (
+                "conv3x3", hw, c, c)
+            assert images == 4
+            events.append(types.SimpleNamespace(name=text, seconds=0.1))
+
+    trace = types.SimpleNamespace(kernel_ops=lambda kinds: [
+        e for e in events if kind_of(e.name) in kinds])
+    share = reader.read(types.SimpleNamespace(
+        trace=trace, peaks=PEAKS["TPU v5 lite"], calls=calls))
+    assert share is not None and 0 < share <= 100

@@ -210,3 +210,93 @@ def test_warm_up_span_counts_linear_attention(monkeypatch):
     (stats,) = [s for name, s in opened if name == "lb.warm_up"]
     assert stats["linear_attention"] == 1
     assert stats["winograd_rows"] + stats["direct"] == 6
+
+
+# -- the ResBlock as two banded convs with fused epilogues
+
+def _res_params(key, c):
+    k = jax.random.split(jax.random.PRNGKey(key), 5)
+    return {"conv1": {"w": jax.random.normal(k[0], (3, 3, c, c))
+                      / np.sqrt(9 * c),
+                      "b": 0.1 * jax.random.normal(k[1], (c,))},
+            "conv2": {"w": jax.random.normal(k[2], (3, 3, c, c))
+                      / np.sqrt(9 * c)},
+            "norm": {"scale": 1.0 + 0.1 * jax.random.normal(k[3], (c,)),
+                     "bias": 0.1 * jax.random.normal(k[4], (c,))}}
+
+
+@pytest.mark.parametrize("c", [16, 256])                 # one and two tiles
+def test_res_block_fused_matches_xla(c):
+    """``x + rms_norm(conv2(silu(conv1(x))))``: the two fused banded convs
+    against the XLA composition, which is the decode's unfused form."""
+    p = _res_params(5, c)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 8, c))
+    want = dcae.res_block(x, p, impl="xla")
+    unfused = x + dcae.rms_norm(
+        ref.conv3x3_ref(dcae.silu(ref.conv3x3_ref(x, p["conv1"]["w"],
+                                                  p["conv1"]["b"])),
+                        p["conv2"]["w"]), p["norm"])
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(unfused))
+    got = dcae.res_block(x, p, impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+def test_tiny_decode_u8_pallas_matches_xla(tiny_vae):
+    """The tiny DC-AE's uint8 decode on the kernels (fused ResBlocks)
+    against the XLA oracle path on the same weights: ±1 LSB."""
+    xla = VAE(TINY, seed=3, impl="xla")
+    z = jax.random.normal(jax.random.PRNGKey(7), (2, 8, 8, 4))
+    got = np.asarray(tiny_vae.decode_u8(z))
+    want = np.asarray(xla.decode_u8(z))
+    assert np.abs(got.astype(int) - want.astype(int)).max() <= 1
+
+
+#: The published block layout (three ResBlock stages, three EfficientViT
+#: stages, three blocks each) at narrow widths: a 128² image.
+LAYOUT = DCAEConfig(name="dcae_layout", latent_channels=4,
+                    block_out_channels=(8, 8, 8, 16, 16, 16),
+                    attention_head_dim=8)
+
+
+def _warm_up_stats(monkeypatch, vae, latent_hwc):
+    from repro.serve import engine
+    opened = []
+
+    class Span:
+        def __init__(self, name, **stats):
+            opened.append((name, dict(stats)))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **stats):
+            opened[-1][1].update(stats)
+
+    monkeypatch.setattr(engine, "TraceAnnotation", Span)
+    engine.DecodeBatcher(vae, buckets=(1,)).prewarm(latent_hwc)
+    (stats,) = [s for name, s in opened if name == "lb.warm_up"]
+    return stats
+
+
+def test_warm_up_counts_fused_epilogues(monkeypatch):
+    """A DC-AE decode of the published layout traces 9 SiLU and 9 RMSNorm
+    + residual epilogues (one of each per ResBlock), 20 banded convs, all
+    on F(2,3), and 9 linear attentions; a KL decode no fused epilogue."""
+    from repro.vae.model import VAEConfig
+    stats = _warm_up_stats(monkeypatch,
+                           VAE(LAYOUT, seed=0, impl="pallas_interpret"),
+                           LAYOUT.latent_shape(128))
+    assert {k: stats[k] for k in ("silu", "rms_residual", "winograd_rows",
+                                  "direct", "linear_attention")} == {
+        "silu": 9, "rms_residual": 9, "winograd_rows": 20, "direct": 0,
+        "linear_attention": 9}
+    kl = VAEConfig(name="tiny_kl", latent_channels=4,
+                   block_out_channels=(16, 32), layers_per_block=1, groups=4)
+    stats = _warm_up_stats(monkeypatch,
+                           VAE(kl, seed=0, impl="pallas_interpret"),
+                           (4, 4, 4))
+    assert stats["silu"] == stats["rms_residual"] == 0
+    assert stats["winograd_rows"] + stats["direct"] > 0

@@ -367,3 +367,74 @@ def test_winograd_weights_reproduce_direct_rows():
                                    atol=1e-5)
         np.testing.assert_allclose(m[1] - m[2] - m[3], y1, rtol=1e-5,
                                    atol=1e-5)
+
+
+# -- the DC-AE ResBlock's fused epilogues (act="silu"; rms= with residual=)
+
+def _epilogue_case(epilogue, h, cout, wt, w_scale=None, rows=8):
+    """The fused conv and its oracle, ``cout`` output channels in tiles of
+    128 (one, two or four of them)."""
+    x = arr((2, h, 6, cout))
+    b = arr((cout,), scale=0.1) if epilogue == "silu" else None
+    kw = ({"act": "silu"} if epilogue == "silu" else
+          {"rms": (1.0 + arr((cout,), scale=0.1), arr((cout,), scale=0.1)),
+           "residual": x})
+    wd = wt if w_scale is None else wt.astype(jnp.float32) * w_scale
+    out = conv3x3(x, wt, b, rows=rows, block_cout=128, interpret=True,
+                  w_scale=w_scale, **kw)
+    return out, ref.conv3x3_epilogue_ref(x, wd, b, **kw)
+
+
+@pytest.mark.parametrize("epilogue", ["silu", "rms_residual"])
+@pytest.mark.parametrize("cout", [128, 256, 512])
+@pytest.mark.parametrize("h,rows,path", [(8, 8, "winograd_rows"),
+                                         (9, 3, "direct")])
+def test_conv3x3_fused_epilogue(epilogue, cout, h, rows, path):
+    """SiLU after the bias, and RMSNorm over every output channel plus
+    the residual, on one, two and four Cout tiles and on both paths."""
+    from repro.kernels.conv3x3 import plan
+    assert plan((2, h, 6, cout), cout, 4, rows, 128, False,
+                epilogue == "rms_residual") == (rows, 128, path)
+    wt = arr((3, 3, cout, cout), scale=1 / np.sqrt(9 * cout))
+    out, want = _epilogue_case(epilogue, h, cout, wt, rows=rows)
+    assert out.shape == want.shape == (2, h, 6, cout)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("epilogue", ["silu", "rms_residual"])
+def test_conv3x3_fused_epilogue_int8(epilogue):
+    """int8 taps: the per-channel scale applies to the accumulator before
+    the bias, then the epilogue."""
+    cout = 256
+    wt = jnp.asarray(R.integers(-127, 128, (3, 3, cout, cout)), jnp.int8)
+    w_scale = jnp.asarray(R.uniform(0.2, 1.0, (cout,)) / (127 * 48),
+                          jnp.float32)
+    out, want = _epilogue_case(epilogue, 8, cout, wt, w_scale)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("hw,c,rows,conv1_rows", [(1024, 128, 8, 8),
+                                                  (512, 256, 8, 16),
+                                                  (256, 512, 4, 16)])
+def test_rms_epilogue_plans_even_bands(hw, c, rows, conv1_rows):
+    """The full-Cout output block and the residual block count against the
+    unchanged VMEM budget, so the DC-AE ResBlock conv2s take 8, 8 and 4
+    rows (all even: F(2,3)); conv1, with its one-tile output block, keeps
+    the plain conv's band."""
+    from repro.kernels.conv3x3 import VMEM_BUDGET, plan, step_vmem_bytes
+    assert plan((4, hw, hw, c), c, 4, 32, 128, False, True) == (
+        rows, 128, "winograd_rows")
+    assert step_vmem_bytes(rows, hw, c, 4, tc=128, taps=12,
+                           rms_cout=c) <= VMEM_BUDGET
+    assert step_vmem_bytes(2 * rows, hw, c, 4, tc=128, taps=12,
+                           rms_cout=c) > VMEM_BUDGET
+    assert plan((4, hw, hw, c), c, 4, 32, 128, False) == (
+        conv1_rows, 128, "winograd_rows")
+
+
+def test_fused_epilogue_refuses_bad_options():
+    x, wt = arr((1, 4, 4, 8)), arr((3, 3, 8, 8))
+    with pytest.raises(ValueError, match="act"):
+        conv3x3(x, wt, act="relu", interpret=True)
+    with pytest.raises(ValueError, match="residual"):
+        conv3x3(x, wt, rms=(arr((8,)), arr((8,))), interpret=True)
